@@ -517,12 +517,12 @@ import dataclasses, json, sys
 sys.path.insert(0, "benchmarks")
 from serve_batched import _serve_stream
 from common import CACHE_DIR, bench_config, task_prompts, trained_params
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 
 cfg = dataclasses.replace(bench_config(), num_layers=4)
 cfg, params = trained_params(cfg, steps=12, cache_dir=CACHE_DIR + "_smoke")
 prompts = [p for ps in task_prompts(cfg, 1).values() for p in ps][:4]
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 out = {}
 for name, mesh_kw in (("single", {}), ("sharded", {"mesh": mesh})):
     out[name] = _serve_stream(cfg, params, prompts, 8,
@@ -536,10 +536,23 @@ def _sharded_arm(out: dict) -> float:
     forced host-device count must be set before jax initializes, and the
     parent bench must keep seeing the real devices). Reuses the parent's
     smoke model cache; both variants land in ``out`` with the
-    us_per_round/tokens_per_step keys ``trend.py`` records."""
+    us_per_round/tokens_per_step keys ``trend.py`` records.
+
+    The child is a CPU-only A/B: it runs only from a CPU parent, because a
+    parent on an accelerator holds its chip and a JAX child would then
+    fail or hang."""
     import json
     import os
     import subprocess
+
+    import jax
+
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "the sharded arm forces 8 CPU devices in a child process and "
+            f"runs only from a CPU parent (this one is on "
+            f"{jax.default_backend()})"
+        )
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
@@ -551,8 +564,9 @@ def _sharded_arm(out: dict) -> float:
         text=True, env=env, cwd=root, timeout=900,
     )
     if proc.returncode != 0:
-        print(f"WARNING: sharded arm subprocess failed:\n{proc.stderr[-2000:]}")
-        return 0.0                   # trips the smoke canary
+        raise RuntimeError(
+            f"sharded arm subprocess failed:\n{proc.stderr[-2000:]}"
+        )
     res = json.loads(proc.stdout.strip().splitlines()[-1])
     sg, sh = res["single"], res["sharded"]
     out["mesh_single_base"], out["mesh_sharded_n8"] = sg, sh

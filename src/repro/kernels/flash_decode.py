@@ -14,6 +14,11 @@ Layouts (per kv-head group g, GQA rep = H // KV):
   kv_pos: (B, S) int32     slot position, -1 = invalid (ring/empty)
   q_pos:  (B, R) int32     absolute position per query row
 Outputs: acc (B, KV, R, hd) f32, m/l (B, KV, R) f32.
+
+Every block keeps the TPU tiling rule (the last two block dims equal the
+array's or divide (8, 128)): the wrappers hand the kernel kv_pos as
+(B, nk, 1, blk) rows, q_pos as a (B, R, 1) column and take m/l back as
+(B, KV, R, 1) columns, squeezing both ends back to the layouts above.
 """
 from __future__ import annotations
 
@@ -45,14 +50,12 @@ def _kernel(
     q = q_ref[0, 0].astype(jnp.float32) * scale        # (R, hd)
     k = k_ref[0, 0].astype(jnp.float32)                # (blk, hd)
     v = v_ref[0, 0].astype(jnp.float32)
-    kvp = kvpos_ref[0]                                 # (blk,)
-    qp = qpos_ref[0]                                   # (R,)
+    kpc = kvpos_ref[0, 0]                              # (1, blk)
+    qpc = qpos_ref[0]                                  # (R, 1)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                                  # (R, blk)
-    qpc = qp[:, None]
-    kpc = kvp[None, :]
     valid = (kpc >= 0) & (kpc <= qpc)
     if kind == "window":
         valid &= kpc > qpc - window
@@ -61,10 +64,10 @@ def _kernel(
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_scr[...]                                # (R, 1)
-    m_new = jnp.maximum(m_prev[:, 0], jnp.max(s, axis=-1))[:, None]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)                             # (R, blk)
     corr = jnp.exp(m_prev - m_new)                     # (R, 1)
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1)[:, None]
+    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
     o_scr[...] = o_scr[...] * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -73,8 +76,17 @@ def _kernel(
     @pl.when(j == nk - 1)
     def _fini():
         acc_ref[0, 0] = o_scr[...]
-        m_ref[0, 0] = m_scr[...][:, 0]
-        l_ref[0, 0] = l_scr[...][:, 0]
+        m_ref[0, 0] = m_scr[...]
+        l_ref[0, 0] = l_scr[...]
+
+
+def _out_shapes(B: int, KV: int, R: int, hd: int) -> list:
+    """acc (B, KV, R, hd) and the m/l columns (B, KV, R, 1), all f32."""
+    return [
+        jax.ShapeDtypeStruct((B, KV, R, hd), jnp.float32),
+        jax.ShapeDtypeStruct((B, KV, R, 1), jnp.float32),
+        jax.ShapeDtypeStruct((B, KV, R, 1), jnp.float32),
+    ]
 
 
 def _paged_kernel(
@@ -106,7 +118,7 @@ def flash_decode_paged_partial(
     kind: str = "causal",
     window: int = 0,
     sink: int = 0,
-    interpret: bool = True,
+    interpret: bool = False,
     scale: float | None = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Block-paged flash-decode partials: the page table rides as a SCALAR
@@ -142,13 +154,13 @@ def flash_decode_paged_partial(
                 (1, 1, P, hd),
                 lambda b, g, j, tbl: (jnp.maximum(tbl[b, j], 0), g, 0, 0),
             ),
-            pl.BlockSpec((1, P), lambda b, g, j, tbl: (b, j)),
-            pl.BlockSpec((1, R), lambda b, g, j, tbl: (b, 0)),
+            pl.BlockSpec((1, 1, 1, P), lambda b, g, j, tbl: (b, j, 0, 0)),
+            pl.BlockSpec((1, R, 1), lambda b, g, j, tbl: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, R, hd), lambda b, g, j, tbl: (b, g, 0, 0)),
-            pl.BlockSpec((1, 1, R), lambda b, g, j, tbl: (b, g, 0)),
-            pl.BlockSpec((1, 1, R), lambda b, g, j, tbl: (b, g, 0)),
+            pl.BlockSpec((1, 1, R, 1), lambda b, g, j, tbl: (b, g, 0, 0)),
+            pl.BlockSpec((1, 1, R, 1), lambda b, g, j, tbl: (b, g, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((R, 1), jnp.float32),
@@ -156,16 +168,17 @@ def flash_decode_paged_partial(
             pltpu.VMEM((R, hd), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, KV, R, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, R), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, R), jnp.float32),
-        ],
+        out_shape=_out_shapes(B, KV, R, hd),
         interpret=interpret,
-    )(page_table, q, k_pages, v_pages, kv_pos, q_pos)
+        name="flash_decode_paged_partial",
+    )(
+        page_table, q, k_pages, v_pages,
+        kv_pos.reshape(B, nk, 1, P), q_pos[..., None],
+    )
+    return acc, m[..., 0], l[..., 0]
 
 
 def flash_decode_partial(
@@ -179,7 +192,7 @@ def flash_decode_partial(
     window: int = 0,
     sink: int = 0,
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
     scale: float | None = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     B, KV, R, hd = q.shape
@@ -193,30 +206,28 @@ def flash_decode_partial(
         _kernel, kind=kind, window=window, sink=sink, scale=scale, nk=nk
     )
     grid = (B, KV, nk)
-    return pl.pallas_call(
+    acc, m, l = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, R, hd), lambda b, g, j: (b, g, 0, 0)),
             pl.BlockSpec((1, 1, blk, hd), lambda b, g, j: (b, g, j, 0)),
             pl.BlockSpec((1, 1, blk, hd), lambda b, g, j: (b, g, j, 0)),
-            pl.BlockSpec((1, blk), lambda b, g, j: (b, j)),
-            pl.BlockSpec((1, R), lambda b, g, j: (b, 0)),
+            pl.BlockSpec((1, 1, 1, blk), lambda b, g, j: (b, j, 0, 0)),
+            pl.BlockSpec((1, R, 1), lambda b, g, j: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, R, hd), lambda b, g, j: (b, g, 0, 0)),
-            pl.BlockSpec((1, 1, R), lambda b, g, j: (b, g, 0)),
-            pl.BlockSpec((1, 1, R), lambda b, g, j: (b, g, 0)),
+            pl.BlockSpec((1, 1, R, 1), lambda b, g, j: (b, g, 0, 0)),
+            pl.BlockSpec((1, 1, R, 1), lambda b, g, j: (b, g, 0, 0)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, KV, R, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, R), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, R), jnp.float32),
-        ],
+        out_shape=_out_shapes(B, KV, R, hd),
         scratch_shapes=[
             pltpu.VMEM((R, 1), jnp.float32),
             pltpu.VMEM((R, 1), jnp.float32),
             pltpu.VMEM((R, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, kv_pos, q_pos)
+        name="flash_decode_partial",
+    )(q, k, v, kv_pos.reshape(B, nk, 1, blk), q_pos[..., None])
+    return acc, m[..., 0], l[..., 0]

@@ -36,17 +36,30 @@ def _kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_scr, *, nk: int):
         o_ref[...] = acc_scr[...] * xs * ws
 
 
-def quantize_rows(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Per-row symmetric int8: returns (x_int8 (M,K), scale (M,1) f32)."""
+def quantize_rows(
+    x: jax.Array, k_axis: str | None = None
+) -> Tuple[jax.Array, jax.Array]:
+    """Per-row symmetric int8: returns (x_int8 (M,K), scale (M,1) f32).
+
+    ``k_axis`` names the mesh axis K is split over (inside ``shard_map``):
+    the row maximum is then taken across it, so every shard quantizes on
+    the unsplit row's grid."""
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
+    if k_axis is not None:
+        amax = jax.lax.pmax(amax, k_axis)
     scale = jnp.maximum(amax, 1e-8) / 127.0
     q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127).astype(jnp.int8)
     return q, scale
 
 
-def quantize_cols(w: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Per-column symmetric int8: returns (w_int8 (K,N), scale (1,N) f32)."""
+def quantize_cols(
+    w: jax.Array, k_axis: str | None = None
+) -> Tuple[jax.Array, jax.Array]:
+    """Per-column symmetric int8: returns (w_int8 (K,N), scale (1,N) f32);
+    ``k_axis`` as in ``quantize_rows``."""
     amax = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=0, keepdims=True)
+    if k_axis is not None:
+        amax = jax.lax.pmax(amax, k_axis)
     scale = jnp.maximum(amax, 1e-8) / 127.0
     q = jnp.clip(jnp.round(w.astype(jnp.float32) / scale), -127, 127).astype(jnp.int8)
     return q, scale
@@ -61,7 +74,7 @@ def int8_matmul(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     M, K = x_q.shape
     N = w_q.shape[1]
@@ -82,4 +95,5 @@ def int8_matmul(
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="int8_matmul",
     )(x_q, w_q, x_scale, w_scale)

@@ -45,7 +45,7 @@ def verify_attention(
     window: int = 0,
     sink: int = 0,
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Returns (B, T, H, hd). TPU path for the verification step."""
     B, T, H, hd0 = q.shape
@@ -109,7 +109,7 @@ def paged_verify_attention(
     kind: str = "causal",
     window: int = 0,
     sink: int = 0,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Block-paged twin of ``verify_attention``: cache partials come from
     ``flash_decode_paged_partial`` (page table scalar-prefetched into the
@@ -149,9 +149,10 @@ def paged_verify_attention(
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "k_axis"))
 def quantized_matmul(
-    x: jax.Array, w: jax.Array, *, interpret: bool | None = None
+    x: jax.Array, w: jax.Array, *, interpret: bool | None = None,
+    k_axis: str | None = None,
 ) -> jax.Array:
     """W8A8 dynamic quantized x @ w with padding to 128-tiles.
 
@@ -159,16 +160,20 @@ def quantized_matmul(
     everywhere else (the kernel only lowers on TPU) — callers on TPU get
     the real kernel without remembering the flag. Pass an explicit bool to
     override (e.g. CPU parity tests force ``interpret=True``).
+
+    ``k_axis``: inside ``shard_map`` with the contraction dim split over
+    that mesh axis, the scales are taken over the whole K and the partial
+    products summed across the axis — the unsplit result on every shard.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     M0, K0 = x.shape
     N0 = w.shape[1]
-    x_q, xs = quantize_rows(x)
-    w_q, ws = quantize_cols(w)
+    x_q, xs = quantize_rows(x, k_axis)
+    w_q, ws = quantize_cols(w, k_axis)
     x_q = _pad_to(_pad_to(x_q, 0, 128), 1, 128)
     w_q = _pad_to(_pad_to(w_q, 0, 128), 1, 128)
     xs = _pad_to(xs, 0, 128, value=1.0)
     ws = _pad_to(ws, 1, 128, value=1.0)
-    out = int8_matmul(x_q, w_q, xs, ws, interpret=interpret)
-    return out[:M0, :N0]
+    out = int8_matmul(x_q, w_q, xs, ws, interpret=interpret)[:M0, :N0]
+    return out if k_axis is None else jax.lax.psum(out, k_axis)

@@ -31,7 +31,7 @@ from repro.analysis.roofline import analyze_compiled
 from repro.config import INPUT_SHAPES, get_config, get_shape
 from repro.configs import ASSIGNED_ARCHS
 from repro.launch import sharding as SH
-from repro.launch.mesh import make_production_mesh, set_global_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import model as M
 from repro.training.optimizer import adamw_init
 from repro.training.train_step import make_train_step
@@ -263,7 +263,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, out_dir: Optional[st
     t0 = time.perf_counter()
     # set_mesh (not `with mesh:`) so with_sharding_constraint sees the
     # abstract mesh during tracing (models.shard_utils.constrain).
-    set_global_mesh(mesh)
+    jax.sharding.set_mesh(mesh)
     fn, args, traffic = BUILDERS[shape.kind](cfg, shape, mesh)
     lowered = fn.lower(*args)
     compiled = lowered.compile()

@@ -4,44 +4,22 @@ A function (not a module constant) so importing this module never touches
 jax device state. The dry-run sets XLA_FLAGS host-device-count=512 BEFORE
 any jax import; tests and benches see the real single CPU device.
 
-JAX-version compat: ``jax.sharding.AxisType`` (and the ``axis_types``
-kwarg of ``jax.make_mesh``) only exist on newer JAX; older releases also
-lack ``jax.sharding.set_mesh``. Both are guarded here so the same code
-runs on either — on old JAX the mesh is built without explicit axis types
-(Auto is the default there anyway) and the global-mesh setter degrades to
-a no-op (sharding constraints then no-op too, see
-``models.shard_utils._mesh_axes``; explicit in_shardings still apply).
+Every mesh is built with ``Auto`` axis types, so GSPMD propagates the
+shardings the code pins with ``with_sharding_constraint``. A process that
+owns serving end to end also makes its mesh global with
+``jax.sharding.set_mesh``, which lets trace-time constraints
+(``models.shard_utils``) see the abstract mesh.
 """
 from __future__ import annotations
 
 import jax
 
 
-def _axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` where supported, ``{}`` on older JAX."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
-
-
-def make_mesh_compat(shape: tuple, axes: tuple):
-    """``jax.make_mesh`` with Auto axis types where the kwarg exists."""
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
-
-
-def set_global_mesh(mesh):
-    """``jax.sharding.set_mesh`` where it exists (needed so trace-time
-    ``with_sharding_constraint`` sees the abstract mesh); on older JAX the
-    mesh is registered as ``models.shard_utils``' concrete fallback, so
-    constraints apply as ``NamedSharding(mesh, spec)`` instead of
-    no-op'ing — same placements on every supported release."""
-    from repro.models import shard_utils
-
-    shard_utils.set_compat_mesh(mesh)
-    setter = getattr(jax.sharding, "set_mesh", None)
-    if setter is not None:
-        setter(mesh)
-    return mesh
+def make_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with ``Auto`` axis types on every axis."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def mesh_from_spec(spec: str):
@@ -64,13 +42,13 @@ def mesh_from_spec(spec: str):
     axes = tuple(a for a in ("pod", "data", "model") if a in sizes)
     if not axes:
         raise ValueError(f"bad mesh spec {spec!r}: no axes given")
-    return make_mesh_compat(tuple(sizes[a] for a in axes), axes)
+    return make_mesh(tuple(sizes[a] for a in axes), axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple:
@@ -81,4 +59,4 @@ def data_axes(mesh) -> tuple:
 
 def make_host_mesh(model: int = 1, data: int = 1):
     """Tiny mesh over real local devices (CPU tests)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

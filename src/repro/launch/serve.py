@@ -21,13 +21,20 @@ Prometheus text at ``/metrics`` while the run is in flight,
 registry snapshot as one JSONL record. Regardless of flags, the LAST
 stdout line is a single machine-readable JSON summary (``kind:
 "serve_summary"``) sourced from the metrics registry.
+
+Compiled programs persist across runs (``configure_compile_cache``): in
+``$JAX_COMPILATION_CACHE_DIR`` when it is set, else in ``.jax_cache`` at
+the root of the checkout.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
+import os
 import time
+from pathlib import Path
 
 import jax
 
@@ -56,6 +63,42 @@ SCHEDULERS = {
 }
 
 
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache and return its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and is
+    left alone; otherwise the cache lives at the fixed ``<repo>/.jax_cache``
+    (the directory is part of each entry's key, so it must not move)."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def init_params(cfg, seed: int = 0, mesh=None) -> dict:
+    """Random weights from ``seed``, made on device by one jitted program.
+
+    On a mesh every parameter is created directly in its tensor-parallel
+    shard (``launch.sharding.param_specs`` as ``out_shardings``), so a model
+    larger than one device's memory never exists whole on any device."""
+    out_shardings = None
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec
+        from repro.launch import sharding as SH
+
+        out_shardings = jax.tree.map(
+            lambda s: NamedSharding(mesh, s), SH.param_specs(cfg, mesh),
+            is_leaf=lambda x: isinstance(x, PartitionSpec),
+        )
+    init = jax.jit(functools.partial(M.init_params, cfg),
+                   out_shardings=out_shardings)
+    return init(jax.random.PRNGKey(seed))
+
+
 def _emit_summary(summary: dict, args) -> None:
     """The one machine-readable final line (+ optional JSONL record)."""
     if args.metrics_jsonl:
@@ -64,16 +107,17 @@ def _emit_summary(summary: dict, args) -> None:
     print(json.dumps(summary, sort_keys=True))
 
 
-def run_batched(cfg, params, args) -> None:
-    """``--mesh`` path: mesh-sharded batched serving rounds."""
-    from repro.launch.mesh import mesh_from_spec, set_global_mesh
+def run_batched(cfg, params, args, mesh):
+    """``--mesh`` path: mesh-sharded batched serving rounds.
+
+    ``mesh`` must be the process's global mesh (``jax.sharding.set_mesh``:
+    this process owns serving end to end, which activates the
+    engine-internal batch pins; libraries embedding the server pass
+    ``mesh=`` only — see the server docstring). Returns the server, the
+    finished requests and the summary record it printed."""
     from repro.serving.scheduler import Request, RequestScheduler, ServeLoop
     from repro.serving.server import BatchedSpecServer
 
-    # this process owns serving end to end, so the global mesh is safe here
-    # (and activates the engine-internal batch pins); libraries embedding
-    # the server pass ``mesh=`` only — see the server docstring
-    mesh = set_global_mesh(mesh_from_spec(args.mesh))
     print(f"mesh: {dict(mesh.shape)} over {len(mesh.devices.flat)} devices")
     srv_kw: dict = {}
     if args.mode != "cascade_fused":
@@ -127,9 +171,10 @@ def run_batched(cfg, params, args) -> None:
         **srv.metrics_summary(),
     }
     _emit_summary(summary, args)
+    return srv, sched.finished, summary
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="vicuna-7b")
     ap.add_argument("--reduced", action="store_true")
@@ -173,15 +218,24 @@ def main():
                     help="wrap the run in jax.profiler.trace(log_dir)")
     ap.add_argument("--metrics-jsonl", default=None,
                     help="append the final summary record to this JSONL file")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main(argv=None):
+    args = parse_args(argv)
+    configure_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = dataclasses.replace(cfg.reduced(), num_layers=8)
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
     if args.mesh:
-        run_batched(cfg, params, args)
+        from repro.launch.mesh import mesh_from_spec
+
+        mesh = mesh_from_spec(args.mesh)
+        jax.sharding.set_mesh(mesh)
+        params = init_params(cfg, 0, mesh)
+        run_batched(cfg, params, args, mesh)
         return
+    params = init_params(cfg, 0)
     prompt = make_task_prompts(SPEC_TASKS[args.task], 1, cfg.vocab_size)[0]
 
     eng = SpecEngine(cfg, params, max_len=1024)

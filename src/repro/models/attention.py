@@ -26,6 +26,7 @@ Mask kinds:
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -190,8 +191,15 @@ def _staged_pallas_partials(
     Same row layout as ``kernels.ops.verify_attention`` (row = r*T + t per
     (batch, kv-head) grid step, head_dim padded to the 128-lane tile);
     interpret mode off-TPU. Returns (acc (B,T,H,hd), m (B,H,T), l (B,H,T)).
+
+    Under a mesh in context the partitioner cannot split a Mosaic kernel,
+    so the call runs under ``shard_map``: batch over the data axes (when
+    they divide B) and KV heads over ``model`` when it divides KV (the
+    ``kv`` head policy); otherwise each model shard runs the whole batch
+    shard.
     """
     from repro.kernels.tree_attention import tree_attention_partial
+    from repro.models.shard_utils import DATA_AXES, _mesh_axes, resolve_spec
 
     B, T, H, hd = q.shape
     KV = k_new.shape[2]
@@ -204,10 +212,21 @@ def _staged_pallas_partials(
     if pad:
         widths = ((0, 0), (0, 0), (0, 0), (0, pad))
         qr, kn, vn = (jnp.pad(a, widths) for a in (qr, kn, vn))
-    acc, m, l = tree_attention_partial(
-        qr, kn, vn, vis,
+    kernel = functools.partial(
+        tree_attention_partial,
         interpret=jax.default_backend() != "tpu", scale=1.0,
     )
+    if _mesh_axes():
+        from jax.sharding import PartitionSpec as P
+
+        dp, kh, _, _ = resolve_spec(qr.shape, DATA_AXES, "model", None, None)
+        heads = P(dp, kh, None, None)
+        kernel = jax.shard_map(
+            kernel, in_specs=(heads, heads, heads, P(dp, None, None)),
+            out_specs=(heads, P(dp, kh, None), P(dp, kh, None)),
+            check_vma=False,
+        )
+    acc, m, l = kernel(qr, kn, vn, vis)
     acc = acc[..., :hd].reshape(B, KV, rep, T, hd).transpose(0, 3, 1, 2, 4)
     return acc.reshape(B, T, H, hd), m.reshape(B, H, T), l.reshape(B, H, T)
 

@@ -1,6 +1,7 @@
 """Primitive layers: norms, RoPE, MLPs, embeddings. Pure functions over pytrees."""
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -35,7 +36,7 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 
 # ----------------------------------------------------------------------- MLP
-def _mm(x: jax.Array, w: jax.Array, quantize) -> jax.Array:
+def _mm(x: jax.Array, w: jax.Array, quantize, w_axes=(None, None)) -> jax.Array:
     """(..., d) @ (d, f), optionally through the W8A8 Pallas kernel.
 
     ``quantize="int8"`` routes the matmul through
@@ -43,16 +44,36 @@ def _mm(x: jax.Array, w: jax.Array, quantize) -> jax.Array:
     weight int8 — the ActivationQuant DSIA's TPU execution; off-TPU the
     kernel runs interpreted, so CPU callers simulate with fake-quantized
     weights instead and never set the flag on hot paths).
+
+    Under a mesh in context the partitioner cannot split the kernel, so it
+    runs under ``shard_map`` with ``w`` split as ``w_axes`` pins it (batch
+    over the data axes): a column split keeps its output columns, a
+    contraction split quantizes on whole-K scales and sums the partials.
     """
     if quantize is None:
         return jnp.einsum("...d,df->...f", x, w)
     if quantize != "int8":
         raise ValueError(f"unsupported quantize mode {quantize!r}")
     from repro.kernels.ops import quantized_matmul
+    from repro.models.shard_utils import DATA_AXES, _mesh_axes, resolve_spec
 
-    lead = x.shape[:-1]
-    out = quantized_matmul(x.reshape(-1, x.shape[-1]), w)
-    return out.reshape(*lead, w.shape[-1]).astype(x.dtype)
+    def mm(x, w, k_axis=None):
+        lead = x.shape[:-1]
+        out = quantized_matmul(x.reshape(-1, x.shape[-1]), w, k_axis=k_axis)
+        return out.reshape(*lead, w.shape[-1]).astype(x.dtype)
+
+    if not _mesh_axes():
+        return mm(x, w)
+    from jax.sharding import PartitionSpec as P
+
+    wk, wn = resolve_spec(w.shape, *w_axes)
+    batch = resolve_spec(x.shape[:1], DATA_AXES) + (None,) * (x.ndim - 2)
+    if wk is not None:
+        mm = functools.partial(mm, k_axis=wk)
+    return jax.shard_map(
+        mm, in_specs=(P(*batch, wk), P(wk, wn)), out_specs=P(*batch, wn),
+        check_vma=False,
+    )(x, w)
 
 
 def mlp_apply(
@@ -69,15 +90,16 @@ def mlp_apply(
     from repro.models.shard_utils import constrain_full
 
     fn = jax.nn.silu if act == "silu" else jax.nn.gelu
-    w_up = constrain_full(params["w_up"], None, "model")
-    w_down = constrain_full(params["w_down"], "model", None)
+    col, row = (None, "model"), ("model", None)
+    w_up = constrain_full(params["w_up"], *col)
+    w_down = constrain_full(params["w_down"], *row)
     if gated:
-        w_gate = constrain_full(params["w_gate"], None, "model")
-        g = fn(_mm(x, w_gate, quantize))
-        u = _mm(x, w_up, quantize)
-        return _mm(g * u, w_down, quantize)
-    h = fn(_mm(x, w_up, quantize))
-    return _mm(h, w_down, quantize)
+        w_gate = constrain_full(params["w_gate"], *col)
+        g = fn(_mm(x, w_gate, quantize, col))
+        u = _mm(x, w_up, quantize, col)
+        return _mm(g * u, w_down, quantize, row)
+    h = fn(_mm(x, w_up, quantize, col))
+    return _mm(h, w_down, quantize, row)
 
 
 def mlp_init(key: jax.Array, d_model: int, d_ff: int, gated: bool, dtype) -> dict:

@@ -11,52 +11,17 @@ Axis = Union[None, str, Tuple[str, ...]]
 # the batch/token-parallel axes in priority order
 DATA_AXES = ("pod", "data")
 
-# Concrete-mesh fallback for JAX releases without jax.sharding.set_mesh /
-# get_abstract_mesh (<= 0.4.x): launch.mesh.set_global_mesh registers the
-# mesh here, and constraints are applied as NamedSharding(mesh, spec) —
-# which works inside jit on every supported release — instead of the
-# bare-PartitionSpec form that needs the abstract-mesh context.
-_COMPAT_MESH = None
-
-
-def set_compat_mesh(mesh) -> None:
-    """Register (or clear, with None) the concrete fallback mesh."""
-    global _COMPAT_MESH
-    _COMPAT_MESH = mesh
-
-
-def _abstract_axes() -> dict:
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        return dict(zip(mesh.axis_names, mesh.axis_sizes))
-    except Exception:
-        return {}
-
 
 def _mesh_axes() -> dict:
-    axes = _abstract_axes()
-    if axes:
-        return axes
-    if _COMPAT_MESH is not None:
-        return {a: _COMPAT_MESH.shape[a] for a in _COMPAT_MESH.axis_names}
-    return {}
+    """Axis sizes of the abstract mesh in context ({} outside any mesh)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
-def _apply_constraint(x: jax.Array, spec: list) -> jax.Array:
-    if not _abstract_axes() and _COMPAT_MESH is not None:
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.NamedSharding(_COMPAT_MESH, P(*spec))
-        )
-    return jax.lax.with_sharding_constraint(x, P(*spec))
-
-
-def constrain(x: jax.Array, *axes: Axis) -> jax.Array:
-    """with_sharding_constraint that only names axes present in the current
-    mesh AND dividing the dimension; a no-op outside any mesh (CPU tests,
-    live engine, or e.g. batch=1 decode where batch can't shard)."""
+def resolve_spec(shape: Tuple[int, ...], *axes: Axis) -> tuple:
+    """Per-dim spec entries naming only the axes of ``axes`` that exist in
+    the current mesh AND divide that dimension (None elsewhere)."""
     sizes = _mesh_axes()
-    if not sizes:
-        return x
 
     def resolve(a, dim):
         if a is None:
@@ -66,14 +31,23 @@ def constrain(x: jax.Array, *axes: Axis) -> jax.Array:
         total = 1
         for t in kept:
             total *= sizes[t]
-        if not kept or total == 0 or dim % total != 0:
+        if not kept or dim % total != 0:
             return None
         return kept if len(kept) > 1 else kept[0]
 
-    spec = [resolve(a, d) for a, d in zip(axes, x.shape)]
+    return tuple(resolve(a, d) for a, d in zip(axes, shape))
+
+
+def constrain(x: jax.Array, *axes: Axis) -> jax.Array:
+    """with_sharding_constraint that only names axes present in the current
+    mesh AND dividing the dimension; a no-op outside any mesh (CPU tests,
+    live engine, or e.g. batch=1 decode where batch can't shard)."""
+    if not _mesh_axes():
+        return x
+    spec = resolve_spec(x.shape, *axes)
     if not any(s for s in spec):
         return x
-    return _apply_constraint(x, spec)
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def data_axis() -> Axis:
@@ -93,24 +67,9 @@ def constrain_full(x: jax.Array, *axes: Axis) -> jax.Array:
     GSPMD then all-gathers the (small) weight shard over 'data' instead of
     gathering the (large) activations — the classic FSDP weight-gather.
     """
-    sizes = _mesh_axes()
-    if not sizes:
+    if not _mesh_axes():
         return x
-
-    def resolve(a, dim):
-        if a is None:
-            return None
-        cand = (a,) if isinstance(a, str) else tuple(a)
-        kept = tuple(t for t in cand if t in sizes)
-        total = 1
-        for t in kept:
-            total *= sizes[t]
-        if not kept or dim % total != 0:
-            return None
-        return kept if len(kept) > 1 else kept[0]
-
-    spec = [resolve(a, d) for a, d in zip(axes, x.shape)]
-    return _apply_constraint(x, spec)
+    return jax.lax.with_sharding_constraint(x, P(*resolve_spec(x.shape, *axes)))
 
 
 def attention_head_policy(num_heads: int, num_kv_heads: int) -> str:

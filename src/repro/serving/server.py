@@ -116,7 +116,7 @@ absence of resharding collectives. See docs/analysis.md.
 
 Mesh-sharded serving (``mesh=``)
 --------------------------------
-Pass a ``("data", "model")`` mesh (``launch.mesh.make_mesh_compat`` /
+Pass a ``("data", "model")`` mesh (``launch.mesh.make_mesh`` /
 ``mesh_from_spec``) and the server places the target AND every draft-bank
 level tensor-parallel over ``model`` (``launch.sharding.param_specs``;
 int8 bank copies inherit the target's placements) and shards the per-slot

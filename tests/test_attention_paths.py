@@ -81,9 +81,9 @@ SPLIT_KV_SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from repro.models.attention import decode_attention
-    from repro.launch.mesh import make_mesh_compat, set_global_mesh
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
-    set_global_mesh(mesh)
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
+    jax.sharding.set_mesh(mesh)
     B, T, H, KV, hd, S = 4, 8, 8, 2, 32, 64
     ks = jax.random.split(jax.random.PRNGKey(0), 8)
     q = jax.random.normal(ks[0], (B, T, H, hd))

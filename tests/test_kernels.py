@@ -134,10 +134,11 @@ def test_flash_decode_invalid_rows_inert(pos):
     paged gather relies on for unallocated pages."""
     B, KV, R_, hd, S = 2, 2, 4, 64, 64
     q, k, v, kv_pos, q_pos = _mk_partial(B, KV, R_, hd, S, 7, pos)
-    acc0, m0, l0 = flash_decode_partial(q, k, v, kv_pos, q_pos, block_s=32)
+    acc0, m0, l0 = flash_decode_partial(q, k, v, kv_pos, q_pos, block_s=32,
+                                       interpret=True)
     bad = jnp.where((kv_pos < 0)[:, None, :, None], 1e4, 0.0)
     acc1, m1, l1 = flash_decode_partial(
-        q, k + bad, v + bad, kv_pos, q_pos, block_s=32)
+        q, k + bad, v + bad, kv_pos, q_pos, block_s=32, interpret=True)
     has_valid = (jnp.asarray(pos) > 0)[:, None, None]   # any committed slot
     assert bool(jnp.all(jnp.where(has_valid, m0 == m1, True)))
     assert bool(jnp.all(jnp.where(has_valid, l0 == l1, True)))
@@ -167,13 +168,14 @@ def test_flash_decode_ring_wraparound():
     abs_pos = jnp.where(abs_pos < pos0, abs_pos, -1).astype(jnp.int32)[None]
     q_pos = jnp.asarray([[pos0, pos0 + 1]], jnp.int32)
     out_ring = _norm(*flash_decode_partial(
-        q, k, v, abs_pos, q_pos, kind="window", window=window, block_s=32))
+        q, k, v, abs_pos, q_pos, kind="window", window=window, block_s=32,
+        interpret=True))
     # sorted layout: same (position, K, V) association, rolled into order
     order = jnp.argsort(jnp.where(abs_pos[0] < 0, 10**6, abs_pos[0]))
     out_sorted = _norm(*flash_decode_partial(
         q, jnp.take(k, order, 2), jnp.take(v, order, 2),
         jnp.take(abs_pos, order, 1), q_pos,
-        kind="window", window=window, block_s=32))
+        kind="window", window=window, block_s=32, interpret=True))
     np.testing.assert_allclose(np.asarray(out_ring), np.asarray(out_sorted),
                                atol=2e-5, rtol=2e-5)
 
@@ -202,10 +204,10 @@ def test_flash_decode_paged_matches_dense(kind, window, sink):
     v_dense = R.ref_paged_gather(pool_v, jnp.asarray(tbl))
     ap, mp, lp = flash_decode_paged_partial(
         q, pool_k, pool_v, jnp.asarray(tbl), kv_pos, q_pos,
-        kind=kind, window=window, sink=sink)
+        kind=kind, window=window, sink=sink, interpret=True)
     ad, md, ld = flash_decode_partial(
         q, k_dense, v_dense, kv_pos, q_pos,
-        kind=kind, window=window, sink=sink, block_s=P)
+        kind=kind, window=window, sink=sink, block_s=P, interpret=True)
     assert bool(jnp.all(ap == ad) and jnp.all(mp == md) and jnp.all(lp == ld))
 
 

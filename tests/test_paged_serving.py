@@ -246,7 +246,7 @@ SCRIPT = textwrap.dedent(
     from repro.analysis.contracts import server_round_contracts
     from repro.config import get_config
     from repro.core.dsia import layer_sparsity
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.models import model as M
     from repro.serving.sampler import SamplingParams
     from repro.serving.server import BatchedSpecServer
@@ -254,7 +254,7 @@ SCRIPT = textwrap.dedent(
     CFG = dataclasses.replace(get_config("vicuna-7b").reduced(), num_layers=3)
     PARAMS = M.init_params(CFG, jax.random.PRNGKey(0))
     SPEC = layer_sparsity(CFG, 0.5)
-    MESH = make_mesh_compat((4, 2), ("data", "model"))
+    MESH = make_mesh((4, 2), ("data", "model"))
     B, ROUNDS = 4, 5
     rng = np.random.default_rng(0)
     prompts = [rng.integers(2, CFG.vocab_size, size=n).astype(np.int32)

@@ -41,15 +41,21 @@ SCRIPT = textwrap.dedent(
     from repro.analysis.contracts import server_round_contracts
     from repro.config import get_config
     from repro.core.dsia import layer_sparsity
-    from repro.launch.mesh import make_mesh_compat
+    from repro.core.latency import CostTracker
+    from repro.launch.mesh import make_mesh
     from repro.models import model as M
     from repro.serving.server import BatchedSpecServer
 
     CFG = dataclasses.replace(get_config("vicuna-7b").reduced(), num_layers=3)
     PARAMS = M.init_params(CFG, jax.random.PRNGKey(0))
     SPEC = layer_sparsity(CFG, 0.5)
-    MESH = make_mesh_compat((4, 2), ("data", "model"))
+    MESH = make_mesh((4, 2), ("data", "model"))
     B, ROUNDS = 4, 6
+    # the adaptive planners price drafts from wall-clock costs; freeze them
+    # at their priors so both placements plan the same rounds (otherwise
+    # dispatch and sync counts agree only when the timings happen to)
+    CostTracker.observe = lambda self, *a, **k: None
+    CostTracker.observe_target = lambda self, *a, **k: None
     rng = np.random.default_rng(0)
     prompts = [rng.integers(2, CFG.vocab_size, size=n).astype(np.int32)
                for n in (8, 12, 6, 10)]

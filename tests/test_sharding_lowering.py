@@ -23,11 +23,11 @@ SCRIPT = textwrap.dedent(
     from repro.config import get_config, get_shape
     from repro.config.base import InputShape
     from repro.launch import sharding as SH
-    from repro.launch.mesh import make_mesh_compat, set_global_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import model as M
 
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
-    set_global_mesh(mesh)
+    mesh = make_mesh((2, 4), ("data", "model"))
+    jax.sharding.set_mesh(mesh)
     results = {}
     for arch in ["gemma3-1b", "qwen2-moe-a2.7b", "mamba2-130m"]:
         cfg = get_config(arch).reduced()
