@@ -1,0 +1,7 @@
+"""``kernels/tree_attention.py``'s share of its roofline (see
+``harness/roofline.py``)."""
+from harness import roofline
+
+
+def read(run):
+    return roofline.share(run, "tree_attention")
