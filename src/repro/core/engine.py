@@ -1052,9 +1052,10 @@ def chain_round(
     live = state["live"]
     pending = state["pending"]
     n = cache["pos"]
-    ctx, chains, have = _round_prologue(
-        cfg, cache, state, draft_k, max_ngram, min_ngram
-    )
+    with jax.named_scope("pld"):
+        ctx, chains, have = _round_prologue(
+            cfg, cache, state, draft_k, max_ngram, min_ngram
+        )
     pld_have = have
     limit = jnp.zeros_like(have)
     if use_draft:
@@ -1080,47 +1081,50 @@ def chain_round(
         # entirely on device. (The scan keeps its static trip inside the
         # taken branch: XLA's known-trip While beats the dynamic-trip form
         # on CPU — see chain_draft_scan(dynamic_steps=...).)
-        chains, have = jax.lax.cond(
-            jnp.any(limit > have), _draft, lambda ops: ops, (chains, have)
+        with jax.named_scope("draft"):
+            chains, have = jax.lax.cond(
+                jnp.any(limit > have), _draft, lambda ops: ops, (chains, have)
+            )
+    with jax.named_scope("verify"):
+        if sampled:
+            # live-gated key advance: a dead slot's stream is dead, and a
+            # chunk-prefilling slot (serving's prefill_chunk wrapper masks it
+            # out of `live`) must reach its first decode round with the exact
+            # key admission bound — the same key the dense admission path
+            # leaves it with. Live slots' uniforms are unchanged (per-slot
+            # threefry streams are independent).
+            new_key, u = verify_lib.round_uniforms(state["key"], draft_k + 1)
+            state["key"] = jnp.where(live[:, None], new_key, state["key"])
+            new_cache, n_chain, new_pending = verify_accept_commit_sampled(
+                cfg, params, cache, pending, chains, have, live,
+                state["temp"], state["topk"], state["topp"], u,
+            )
+        else:
+            new_cache, nxt, n_chain, new_pending = verify_accept_commit(
+                cfg, params, cache, pending, chains, have, live
+            )
+    with jax.named_scope("commit"):
+        n_acc = jnp.where(live, n_chain + 1, 0).astype(jnp.int32)
+        acc_tok = jnp.concatenate([pending[:, None], chains], axis=1)
+        state["ctx"] = _commit_ctx(ctx, n, acc_tok, n_acc)
+        state["pending"] = jnp.where(live, new_pending, pending).astype(jnp.int32)
+        # Eq. 4 EMA over the NEURAL drafter: first neural position's outcome,
+        # only when the PLD prefix was fully accepted (parent-accepted rule)
+        obs = live & (have > pld_have) & (n_chain >= pld_have)
+        outcome = (n_chain > pld_have).astype(jnp.float32)
+        (state["alpha"], state["hist"], state["hist_n"],
+         state["hist_ptr"]) = ema_update(
+            state["alpha"], state["hist"], state["hist_n"], state["hist_ptr"],
+            outcome, obs,
         )
-    if sampled:
-        # live-gated key advance: a dead slot's stream is dead, and a
-        # chunk-prefilling slot (serving's prefill_chunk wrapper masks it
-        # out of `live`) must reach its first decode round with the exact
-        # key admission bound — the same key the dense admission path
-        # leaves it with. Live slots' uniforms are unchanged (per-slot
-        # threefry streams are independent).
-        new_key, u = verify_lib.round_uniforms(state["key"], draft_k + 1)
-        state["key"] = jnp.where(live[:, None], new_key, state["key"])
-        new_cache, n_chain, new_pending = verify_accept_commit_sampled(
-            cfg, params, cache, pending, chains, have, live,
-            state["temp"], state["topk"], state["topp"], u,
-        )
-    else:
-        new_cache, nxt, n_chain, new_pending = verify_accept_commit(
-            cfg, params, cache, pending, chains, have, live
-        )
-    n_acc = jnp.where(live, n_chain + 1, 0).astype(jnp.int32)
-    acc_tok = jnp.concatenate([pending[:, None], chains], axis=1)
-    state["ctx"] = _commit_ctx(ctx, n, acc_tok, n_acc)
-    state["pending"] = jnp.where(live, new_pending, pending).astype(jnp.int32)
-    # Eq. 4 EMA over the NEURAL drafter: first neural position's outcome,
-    # only when the PLD prefix was fully accepted (parent-accepted rule)
-    obs = live & (have > pld_have) & (n_chain >= pld_have)
-    outcome = (n_chain > pld_have).astype(jnp.float32)
-    (state["alpha"], state["hist"], state["hist_n"],
-     state["hist_ptr"]) = ema_update(
-        state["alpha"], state["hist"], state["hist_n"], state["hist_ptr"],
-        outcome, obs,
-    )
-    # per-slot round facts: the server's pipelined drain sums "drafted"
-    # when it resolves the future, and the device telemetry accumulator
-    # (serving/telemetry.py) folds the rest without any extra dispatch
-    out = {
-        "acc": acc_tok, "n_acc": n_acc,
-        "drafted": jnp.maximum(have - pld_have, 0),
-        "pld_have": pld_have, "budget": limit,
-    }
+        # per-slot round facts: the server's pipelined drain sums "drafted"
+        # when it resolves the future, and the device telemetry accumulator
+        # (serving/telemetry.py) folds the rest without any extra dispatch
+        out = {
+            "acc": acc_tok, "n_acc": n_acc,
+            "drafted": jnp.maximum(have - pld_have, 0),
+            "pld_have": pld_have, "budget": limit,
+        }
     return new_cache, _pin_batch(state, dax), _pin_batch(out, dax)
 
 
@@ -1164,13 +1168,14 @@ def tree_round(
     pending = state["pending"]
     n = cache["pos"]
     B = live.shape[0]
-    ctx, chains, have = _round_prologue(
-        cfg, cache, state, draft_k, max_ngram, min_ngram
-    )
+    with jax.named_scope("pld"):
+        ctx, chains, have = _round_prologue(
+            cfg, cache, state, draft_k, max_ngram, min_ngram
+        )
+        tokens, parents, depth, p_acc, mask, count = tree_seed_device(
+            pending, chains, have, bucket, pld_alpha
+        )
     pld_have = have
-    tokens, parents, depth, p_acc, mask, count = tree_seed_device(
-        pending, chains, have, bucket, pld_alpha
-    )
     first_neural = jnp.full((B,), -1, jnp.int32)
     limits = jnp.zeros((B,), jnp.int32)
     if use_draft and expansions > 0:
@@ -1196,58 +1201,62 @@ def tree_round(
 
         # runtime skip (see chain_round): PLD-only / routing-stopped rounds
         # pay no expansion compute inside the same executable
-        tokens, parents, depth, p_acc, mask, count, first_neural = (
-            jax.lax.cond(
-                jnp.any(limits > 0), _grow, lambda ops: ops,
-                (tokens, parents, depth, p_acc, mask, count, first_neural),
+        with jax.named_scope("draft"):
+            tokens, parents, depth, p_acc, mask, count, first_neural = (
+                jax.lax.cond(
+                    jnp.any(limits > 0), _grow, lambda ops: ops,
+                    (tokens, parents, depth, p_acc, mask, count,
+                     first_neural),
+                )
             )
+    with jax.named_scope("verify"):
+        if sampled:
+            # live-gated key advance — see chain_round: frozen keys for dead
+            # and chunk-prefilling slots, identical uniforms for live ones
+            new_key, u = verify_lib.round_uniforms(state["key"], bucket)
+            state["key"] = jnp.where(live[:, None], new_key, state["key"])
+            new_cache, path, n_acc, bonus = tree_verify_accept_commit_sampled(
+                cfg, params, cache, tokens, parents, depth, mask, count, live,
+                state["temp"], state["topk"], state["topp"], u,
+                attn_backend=attn_backend,
+            )
+        else:
+            new_cache, path, n_acc, bonus = tree_verify_accept_commit(
+                cfg, params, cache, tokens, parents, depth, mask, count, live,
+                attn_backend=attn_backend,
+            )
+    with jax.named_scope("commit"):
+        acc_tok = jnp.take_along_axis(tokens, path, axis=1)          # (B, N)
+        state["ctx"] = _commit_ctx(ctx, n, acc_tok, n_acc)
+        state["pending"] = jnp.where(live, bonus, pending).astype(jnp.int32)
+        # Eq. 4 EMA at the slot's first NEURAL node (parent-accepted rule; the
+        # same bookkeeping the split round does on host after draining)
+        N = tokens.shape[1]
+        t_ids = jnp.arange(N)
+        acc_mask = jnp.zeros((B, N), bool).at[
+            jnp.arange(B)[:, None],
+            jnp.where(t_ids[None, :] < n_acc[:, None], path, N),
+        ].set(True, mode="drop")
+        fn_c = jnp.clip(first_neural, 0, N - 1)
+        fn_parent = jnp.take_along_axis(parents, fn_c[:, None], 1)[:, 0]
+        parent_ok = jnp.take_along_axis(
+            acc_mask, jnp.clip(fn_parent, 0, N - 1)[:, None], 1
+        )[:, 0]
+        obs = live & (first_neural >= 0) & (fn_parent >= 0) & parent_ok
+        outcome = jnp.take_along_axis(acc_mask, fn_c[:, None], 1)[:, 0]
+        (state["alpha"], state["hist"], state["hist_n"],
+         state["hist_ptr"]) = ema_update(
+            state["alpha"], state["hist"], state["hist_n"], state["hist_ptr"],
+            outcome.astype(jnp.float32), obs,
         )
-    if sampled:
-        # live-gated key advance — see chain_round: frozen keys for dead
-        # and chunk-prefilling slots, identical uniforms for live ones
-        new_key, u = verify_lib.round_uniforms(state["key"], bucket)
-        state["key"] = jnp.where(live[:, None], new_key, state["key"])
-        new_cache, path, n_acc, bonus = tree_verify_accept_commit_sampled(
-            cfg, params, cache, tokens, parents, depth, mask, count, live,
-            state["temp"], state["topk"], state["topp"], u,
-            attn_backend=attn_backend,
-        )
-    else:
-        new_cache, path, n_acc, bonus = tree_verify_accept_commit(
-            cfg, params, cache, tokens, parents, depth, mask, count, live,
-            attn_backend=attn_backend,
-        )
-    acc_tok = jnp.take_along_axis(tokens, path, axis=1)          # (B, N)
-    state["ctx"] = _commit_ctx(ctx, n, acc_tok, n_acc)
-    state["pending"] = jnp.where(live, bonus, pending).astype(jnp.int32)
-    # Eq. 4 EMA at the slot's first NEURAL node (parent-accepted rule; the
-    # same bookkeeping the split round does on host after draining)
-    N = tokens.shape[1]
-    t_ids = jnp.arange(N)
-    acc_mask = jnp.zeros((B, N), bool).at[
-        jnp.arange(B)[:, None],
-        jnp.where(t_ids[None, :] < n_acc[:, None], path, N),
-    ].set(True, mode="drop")
-    fn_c = jnp.clip(first_neural, 0, N - 1)
-    fn_parent = jnp.take_along_axis(parents, fn_c[:, None], 1)[:, 0]
-    parent_ok = jnp.take_along_axis(
-        acc_mask, jnp.clip(fn_parent, 0, N - 1)[:, None], 1
-    )[:, 0]
-    obs = live & (first_neural >= 0) & (fn_parent >= 0) & parent_ok
-    outcome = jnp.take_along_axis(acc_mask, fn_c[:, None], 1)[:, 0]
-    (state["alpha"], state["hist"], state["hist_n"],
-     state["hist_ptr"]) = ema_update(
-        state["alpha"], state["hist"], state["hist_n"], state["hist_ptr"],
-        outcome.astype(jnp.float32), obs,
-    )
-    # per-slot round facts (see chain_round): drained sums + telemetry
-    # accumulation happen downstream, inside the same executable or on
-    # already-resolved futures — never as an extra dispatch
-    out = {
-        "acc": acc_tok, "n_acc": n_acc,
-        "drafted": jnp.clip(count - pld_have - 1, 0, None),
-        "pld_have": pld_have, "budget": limits,
-    }
+        # per-slot round facts (see chain_round): drained sums + telemetry
+        # accumulation happen downstream, inside the same executable or on
+        # already-resolved futures — never as an extra dispatch
+        out = {
+            "acc": acc_tok, "n_acc": n_acc,
+            "drafted": jnp.clip(count - pld_have - 1, 0, None),
+            "pld_have": pld_have, "budget": limits,
+        }
     return new_cache, _pin_batch(state, dax), _pin_batch(out, dax)
 
 

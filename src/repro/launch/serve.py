@@ -15,10 +15,12 @@ docs/sharding.md). Off-accelerator, force host devices first:
 
 Observability (docs/observability.md): ``--metrics-port`` serves live
 Prometheus text at ``/metrics`` while the run is in flight,
-``--trace-out`` records Chrome-trace spans of the host-loop phases
-(open in Perfetto), ``--profile-dir`` wraps the run in
-``jax.profiler.trace``, and ``--metrics-jsonl`` appends the end-of-run
-registry snapshot as one JSONL record. Regardless of flags, the LAST
+``--profile-dir`` wraps the run in ``jax.profiler.trace`` and writes the
+XPlane file plus a Perfetto trace (``perfetto_trace.json.gz``, open in
+https://ui.perfetto.dev) in which the loop's and the server's phase spans
+(``loop.*``, ``serve.*``) sit on the same clock as the device ops, and
+``--metrics-jsonl`` appends the end-of-run registry snapshot as one JSONL
+record. Regardless of flags, the LAST
 stdout line is a single machine-readable JSON summary (``kind:
 "serve_summary"``) sourced from the metrics registry.
 
@@ -49,7 +51,7 @@ from repro.core.engine import SpecEngine
 from repro.data import SPEC_TASKS, make_task_prompts
 from repro.models import model as M
 from repro.serving.exporters import JsonlSink, MetricsHTTPServer
-from repro.serving.telemetry import TraceRecorder, profiler_trace
+from repro.serving.telemetry import profiler_trace
 
 SCHEDULERS = {
     "ar": lambda e, cfg: ARScheduler(e),
@@ -143,11 +145,10 @@ def run_batched(cfg, params, args, mesh):
                 if args.metrics_port is not None else None)
     if endpoint is not None:
         print(f"metrics: {endpoint.url}")
-    trace = TraceRecorder() if args.trace_out else None
     sched = RequestScheduler(args.batch)
     for p in make_task_prompts(SPEC_TASKS[args.task], args.batch, cfg.vocab_size):
         sched.submit(Request(prompt=p, max_new_tokens=args.tokens))
-    loop = ServeLoop(srv, sched, trace=trace)
+    loop = ServeLoop(srv, sched)
     t0 = time.perf_counter()
     with profiler_trace(args.profile_dir):
         while sched.busy:
@@ -157,9 +158,6 @@ def run_batched(cfg, params, args, mesh):
     tok = sum(len(r.generated) for r in sched.finished)
     print(f"mode={args.mode} mesh={args.mesh} requests={len(sched.finished)} "
           f"tokens={tok} time={dt:.2f}s ({dt/max(tok,1)*1e3:.1f} ms/tok)")
-    if trace is not None:
-        trace.save(args.trace_out)
-        print(f"trace: {args.trace_out} (open in https://ui.perfetto.dev)")
     if endpoint is not None:
         endpoint.close()
     summary = {
@@ -211,11 +209,10 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--metrics-port", type=int, default=None,
                     help="serve Prometheus /metrics on this port (0 = "
                          "ephemeral; batched path)")
-    ap.add_argument("--trace-out", default=None,
-                    help="write Chrome trace-event JSON of the host-loop "
-                         "phases here (batched path)")
     ap.add_argument("--profile-dir", default=None,
-                    help="wrap the run in jax.profiler.trace(log_dir)")
+                    help="profile the run into this directory: the XPlane "
+                         "file and a Perfetto trace of the host phase "
+                         "spans beside the device ops")
     ap.add_argument("--metrics-jsonl", default=None,
                     help="append the final summary record to this JSONL file")
     return ap.parse_args(argv)

@@ -1,7 +1,5 @@
 """Serving runtime: samplers, request scheduling, batched speculative server."""
-from repro.serving.telemetry import (
-    MetricsRegistry, StatsView, TraceRecorder,
-)
+from repro.serving.telemetry import MetricsRegistry, StatsView, span
 from repro.serving.exporters import JsonlSink, MetricsHTTPServer
 from repro.serving.draft_bank import DraftBank, DraftLevel
 from repro.serving.sampler import sample_token
@@ -11,6 +9,6 @@ from repro.serving.server import BatchedSpecServer
 __all__ = [
     "sample_token", "Request", "RequestScheduler", "ServeLoop",
     "BatchedSpecServer", "DraftBank", "DraftLevel",
-    "MetricsRegistry", "StatsView", "TraceRecorder",
+    "MetricsRegistry", "StatsView", "span",
     "JsonlSink", "MetricsHTTPServer",
 ]

@@ -14,9 +14,9 @@ Formats
 - ``JsonlSink`` — appends one JSON object per line; used for periodic
   registry snapshots and for the end-of-run summary line in
   launch/serve.py.
-- Chrome trace-event JSON is produced by TraceRecorder.save (re-exported
-  here as ``write_chrome_trace`` for symmetry); open the file in Perfetto
-  (https://ui.perfetto.dev) or chrome://tracing.
+
+Traces are the JAX profiler's (``telemetry.span``, ``--profile-dir``);
+nothing here writes one.
 """
 
 from __future__ import annotations
@@ -26,18 +26,12 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import IO, Any, Optional, Union
 
-from .telemetry import MetricsRegistry, TraceRecorder
+from .telemetry import MetricsRegistry
 
 __all__ = [
     "MetricsHTTPServer",
     "JsonlSink",
-    "write_chrome_trace",
 ]
-
-
-def write_chrome_trace(trace: TraceRecorder, path: str) -> None:
-    """Write recorded spans as Chrome trace-event JSON (Perfetto-viewable)."""
-    trace.save(path)
 
 
 class _MetricsHandler(BaseHTTPRequestHandler):

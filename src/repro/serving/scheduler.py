@@ -9,8 +9,9 @@ every mode exposes the same add_request/step/release slot contract.
 Observability (docs/observability.md): the loop measures what only IT can
 see — per-request TTFT/TPOT/ITL (token arrivals are logged as the loop
 routes them, so pipelined sync batches are attributed at their real drain
-times), queue depth and slot occupancy gauges, and Chrome-trace spans for
-the host-loop phases (admit / dispatch / drain / route / retire). Overshoot
+times), queue depth and slot occupancy gauges, and the ``loop.*`` spans of
+its phases (``telemetry.PHASES``: step / drain / admit / route / retire,
+with the server's ``serve.round`` inside the step). Overshoot
 tokens trimmed at retire are EXCLUDED from per-request token counts and
 TPOT (they were never delivered), and counted separately so drained device
 telemetry reconciles exactly with the routed streams."""
@@ -25,12 +26,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.serving.sampler import SamplingParams
-from repro.serving.telemetry import (
-    Histogram,
-    MetricsRegistry,
-    TraceRecorder,
-    maybe_span,
-)
+from repro.serving.telemetry import Histogram, MetricsRegistry, PhaseSpans
 
 _ids = itertools.count()
 
@@ -150,16 +146,15 @@ class ServeLoop:
     were in flight when it crossed the line — the surplus is trimmed at
     retire, exactly like the synchronous path trims a long accepted chain.
 
-    ``metrics`` defaults to the server's own registry (so loop metrics and
-    server telemetry land on one /metrics endpoint); ``trace`` (a
-    ``TraceRecorder``) turns on Chrome-trace spans for the loop phases."""
+    ``metrics`` defaults to the server's own registry (so loop metrics,
+    phase seconds and server telemetry land on one /metrics endpoint, and
+    ``server.stats["loop_time"]`` reads the loop's own phase counter)."""
 
     def __init__(
         self,
         server,
         scheduler: RequestScheduler,
         metrics: Optional[MetricsRegistry] = None,
-        trace: Optional[TraceRecorder] = None,
     ):
         self.server = server
         self.scheduler = scheduler
@@ -167,7 +162,7 @@ class ServeLoop:
             metrics if metrics is not None
             else getattr(server, "metrics", None)
         ) or MetricsRegistry()
-        self.trace = trace
+        self._span = PhaseSpans(self.metrics)
         self._slot_req: Dict[int, Request] = {}
         self._req_slot: Dict[int, int] = {}   # request_id -> slot
 
@@ -209,6 +204,10 @@ class ServeLoop:
             ).observe(gap)
 
     def step_once(self) -> Dict[int, List[int]]:
+        with self._span("loop.step"):
+            return self._step()
+
+    def _step(self) -> Dict[int, List[int]]:
         out: Dict[int, List[int]] = {}
         will_admit = bool(self.scheduler.queue) and (
             len(self.scheduler.active) < self.scheduler.max_batch
@@ -218,12 +217,13 @@ class ServeLoop:
             # OLD slot mapping before any slot is re-bound
             flush = getattr(self.server, "flush", None)
             if flush is not None:
-                with maybe_span(self.trace, "drain"):
+                with self._span("loop.drain"):
                     out = flush()
-                self._route(out)
-        with maybe_span(self.trace, "admit"):
-            for slot in self.scheduler.admit():
-                req = self.scheduler.active[slot]
+                    self._route(out)
+        for slot in self.scheduler.admit():
+            req = self.scheduler.active[slot]
+            with self._span("loop.admit", request_id=req.request_id,
+                            slot=slot):
                 # the request's token budget rides admission so paged
                 # servers can size the slot's KV page allocation to
                 # prompt + budget instead of a full max_len reservation
@@ -237,22 +237,22 @@ class ServeLoop:
                         slot, req.prompt,
                         max_new_tokens=req.max_new_tokens,
                     )
-                self._slot_req[slot] = req
-                self._req_slot[req.request_id] = slot
-        # the "dispatch" span times the HOST side of a round (pipelined
-        # rounds return before the device finishes; device completion is
-        # accounted by the server's device_wait counter at drain points)
-        with maybe_span(self.trace, "dispatch"):
-            step_out = self.server.step()
-        with maybe_span(self.trace, "route"):
+            self._slot_req[slot] = req
+            self._req_slot[req.request_id] = slot
+        # serve.round (the server's own span) times the HOST side of a
+        # round: pipelined rounds return before the device finishes, and
+        # the server's serve.wait spans count the time blocked on results
+        step_out = self.server.step()
+        with self._span("loop.route"):
             self._route(step_out)
             for slot, toks in step_out.items():
                 out.setdefault(slot, []).extend(toks)
-        with maybe_span(self.trace, "retire"):
-            for req in self.scheduler.retire():
+        for req in self.scheduler.retire():
+            slot = self._req_slot.pop(req.request_id)
+            with self._span("loop.retire", request_id=req.request_id,
+                            slot=slot):
                 trimmed = max(len(req.generated) - req.max_new_tokens, 0)
                 req.generated = req.generated[: req.max_new_tokens]
-                slot = self._req_slot.pop(req.request_id)
                 del self._slot_req[slot]
                 self.server.release(slot)
                 self._observe_retired(req, trimmed)

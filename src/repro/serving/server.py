@@ -150,7 +150,6 @@ from __future__ import annotations
 
 import functools
 import os
-import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -746,9 +745,11 @@ class BatchedSpecServer:
                 pf_sampled = sampling is not None
 
                 def round_core(p, cache, state, *rest):
-                    cache, state = prefill_chunk_stage(
-                        cfg, p, cache, state, chunk=chunk, sampled=pf_sampled
-                    )
+                    with jax.named_scope("prefill"):
+                        cache, state = prefill_chunk_stage(
+                            cfg, p, cache, state, chunk=chunk,
+                            sampled=pf_sampled,
+                        )
                     live0 = state["live"]
                     state = dict(
                         state,
@@ -785,6 +786,9 @@ class BatchedSpecServer:
         # .STATS_METRICS) so pinned test reads and the /metrics endpoint
         # can never drift apart
         self.stats: TM.StatsView = TM.StatsView(self.metrics)
+        # host phase spans (telemetry.PHASES): serve.round around every
+        # step, and inside it plan / dispatch / wait / commit / drain
+        self._span = TM.PhaseSpans(self.metrics)
 
     # ------------------------------------------------------------ admission
     def add_request(
@@ -866,14 +870,22 @@ class BatchedSpecServer:
             # B=1 prefill cache: batch can't shard, but layout must match the
             # sharded weights it is written from (TP head placement)
             c1 = jax.device_put(c1, self._c1_sharding)
-        last, c1 = self._prefill1(self.params, {"tokens": jnp.asarray(prompt[None])}, c1)
-        slot_d = jnp.asarray(slot, jnp.int32)
-        if self.paged:
-            self.cache = self._write_slot_fn(
-                self.cache, c1, slot_d, jnp.asarray(table_row)
+        with self._span("serve.dispatch"):
+            last, c1 = self._prefill1(
+                self.params, {"tokens": jnp.asarray(prompt[None])}, c1
             )
-        else:
-            self.cache = self._write_slot_fn(self.cache, c1, slot_d)
+            slot_d = jnp.asarray(slot, jnp.int32)
+            if self.paged:
+                self.cache = self._write_slot_fn(
+                    self.cache, c1, slot_d, jnp.asarray(table_row)
+                )
+            else:
+                self.cache = self._write_slot_fn(self.cache, c1, slot_d)
+        # the first token is read from the prefill's logits below: the
+        # block on them is device time (``device_wait``), not host time.
+        # Not a round's sync, so ``host_syncs`` does not count it.
+        with self._span("serve.wait"):
+            last = jax.block_until_ready(last)
         # device round state: pending/live/context row + a fresh Eq. 4
         # estimator seeded with the draft's cold-start prior
         row = np.zeros(self.max_len, np.int32)
@@ -1353,12 +1365,13 @@ class BatchedSpecServer:
 
         Returns (chains (B,k) int32, have (B,) int32). The neural fill-in is
         a single fused scan dispatch covering every slot and draft step."""
-        chains, have = self._pld_chains()
-        limit = np.zeros(self.B, np.int32)
-        for b in range(self.B):
-            if self.live[b]:
-                limit[b] = self._slot_limit(b)
-        self._last_limit = limit.copy()   # split-round telemetry (budget_hist)
+        with self._span("serve.plan"):
+            chains, have = self._pld_chains()
+            limit = np.zeros(self.B, np.int32)
+            for b in range(self.B):
+                if self.live[b]:
+                    limit[b] = self._slot_limit(b)
+            self._last_limit = limit.copy()   # split-round telemetry
         if self.draft_spec is None:
             return chains, have
         if self.fused:
@@ -1371,25 +1384,21 @@ class BatchedSpecServer:
         steps = int(np.max(np.where(limit > have, limit, 0), initial=0))
         if steps == 0:
             return chains, have
-        t0 = time.perf_counter()
-        ch_d, hv_d = jax.block_until_ready(
-            self._draft_fn(steps)(
+        with self._span("serve.dispatch") as d:
+            out = self._draft_fn(steps)(
                 self.params, self.cache,
                 jnp.asarray(self.pending, jnp.int32),
                 jnp.asarray(chains), jnp.asarray(have), jnp.asarray(limit),
                 self._gates,
             )
-        )
-        dt = time.perf_counter() - t0
-        chains, have = np.asarray(ch_d), np.asarray(hv_d)
-        self.stats["draft_dispatches"] += 1
-        self.stats["draft_time"] += dt
-        self.stats["host_syncs"] += 1
-        self.stats["device_wait"] += dt
-        self.stats["drafted_tokens"] += steps
-        # per-draft-step latency (the whole batch advances one token per
-        # step) -> c_hat = draft-step / verify-round, the c in T_SD
-        self.costs.observe("chain_draft", dt, tokens=steps)
+        (ch_d, hv_d), dt = self._wait(out, d)
+        with self._span("serve.commit"):
+            chains, have = np.asarray(ch_d), np.asarray(hv_d)
+            self.stats["draft_dispatches"] += 1
+            self.stats["drafted_tokens"] += steps
+            # per-draft-step latency (the whole batch advances one token
+            # per step) -> c_hat = draft-step / verify-round, the c in T_SD
+            self.costs.observe("chain_draft", dt, tokens=steps)
         return chains, have
 
     def _propose_legacy(self, chains, have, limit):
@@ -1403,16 +1412,13 @@ class BatchedSpecServer:
             toks = np.concatenate(
                 [self.pending[:, None], chains[:, :j]], axis=1
             ).astype(np.int32)
-            t0 = time.perf_counter()
-            logits, _ = self._decode(
-                self.params, self.cache, jnp.asarray(toks), self._gates
-            )
-            nxt = np.asarray(jnp.argmax(logits[:, -1], -1))
-            dt = time.perf_counter() - t0
+            with self._span("serve.dispatch") as d:
+                logits, _ = self._decode(
+                    self.params, self.cache, jnp.asarray(toks), self._gates
+                )
+                nxt = jnp.argmax(logits[:, -1], -1)
+            nxt = np.asarray(self._wait(nxt, d)[0])
             self.stats["draft_dispatches"] += 1
-            self.stats["draft_time"] += dt
-            self.stats["host_syncs"] += 1
-            self.stats["device_wait"] += dt
             fill = (have <= j) & (j < limit)
             chains[fill, j] = nxt[fill]
             have = np.maximum(have, np.where(fill, j + 1, have)).astype(np.int32)
@@ -1447,10 +1453,9 @@ class BatchedSpecServer:
         if not self._inflight:
             return
         outs, self._inflight = self._inflight, []
-        t0 = time.perf_counter()
-        jax.block_until_ready([o["n_acc"] for o in outs])
+        with self._span("serve.wait"):
+            jax.block_until_ready([o["n_acc"] for o in outs])
         self.stats["host_syncs"] += 1
-        self.stats["device_wait"] += time.perf_counter() - t0
         for o in outs:
             acc, n_acc = np.asarray(o["acc"]), np.asarray(o["n_acc"])
             self.stats["drafted_tokens"] += int(np.asarray(o["drafted"]).sum())
@@ -1467,8 +1472,9 @@ class BatchedSpecServer:
         slot. The pipelined loop calls this every ``sync_every`` rounds and
         before re-binding a slot (admission/retire); split rounds have
         nothing in flight and this is a cheap no-op."""
-        self._drain()
-        self._drain_telemetry()
+        with self._span("serve.drain"):
+            self._drain()
+            self._drain_telemetry()
         out, self._out_buf = self._out_buf, {}
         return out
 
@@ -1544,18 +1550,21 @@ class BatchedSpecServer:
         return immediately — accepted tokens are drained from already-
         resolved device futures every ``sync_every`` rounds, so the device
         never waits for the host between rounds."""
-        if self.telemetry:
-            # the donated buffer is re-bound in the same statement, like
-            # the cache/state (REPRO002) — accumulation happened inside
-            # the one round dispatch
-            self.cache, self.dstate, self._telem_dev, out = self._round_fn(
-                self.params, self.cache, self.dstate, self._telem_dev,
-                self._c_dev, self._gates,
-            )
-        else:
-            self.cache, self.dstate, out = self._round_fn(
-                self.params, self.cache, self.dstate, self._c_dev, self._gates
-            )
+        with self._span("serve.dispatch"):
+            if self.telemetry:
+                # the donated buffer is re-bound in the same statement,
+                # like the cache/state (REPRO002) — accumulation happened
+                # inside the one round dispatch
+                (self.cache, self.dstate, self._telem_dev,
+                 out) = self._round_fn(
+                    self.params, self.cache, self.dstate, self._telem_dev,
+                    self._c_dev, self._gates,
+                )
+            else:
+                self.cache, self.dstate, out = self._round_fn(
+                    self.params, self.cache, self.dstate, self._c_dev,
+                    self._gates,
+                )
         self._inflight.append(out)
         self.stats["steps"] += 1
         self.stats["round_dispatches"] += 1
@@ -1571,174 +1580,196 @@ class BatchedSpecServer:
         """One speculative round for the whole batch; returns new tokens
         (in pipelined single mode: the tokens drained *so far* — possibly
         from earlier rounds, possibly empty between sync points)."""
-        if self.round_mode == "single":
-            return self._step_single()
-        if self.mode == "tree_fused":
-            return self._step_tree()
-        if self.mode == "cascade_fused":
-            return self._step_cascade()
+        with self._span("serve.round"):
+            if self.round_mode == "single":
+                return self._step_single()
+            if self.mode == "tree_fused":
+                return self._step_tree()
+            if self.mode == "cascade_fused":
+                return self._step_cascade()
+            return self._step_chain()
+
+    def _wait(self, out, dispatched: TM.span) -> Tuple[Any, float]:
+        """Block on a host-synced dispatch's outputs under ``serve.wait``
+        (the ``device_wait`` counter). ``dispatched`` is the
+        ``serve.dispatch`` span in which the arguments were built and the
+        jitted program called. Returns the resolved outputs and the seconds
+        from the call to the result — the interval DyTC's cost trackers
+        price."""
+        with self._span("serve.wait") as w:
+            out = jax.block_until_ready(out)
+        self.stats["host_syncs"] += 1
+        return out, w.t1 - dispatched.t0
+
+    def _step_chain(self) -> Dict[int, List[int]]:
+        """One split chain round (``chain_fused`` split / ``legacy``): host
+        PLD + the drafting dispatch(es), then one verify dispatch."""
         chains, have = self._propose()
-        t0 = time.perf_counter()
         if self.sampling is not None:
             ds = self.dstate
-            new_cache, n_chain, new_pending, new_key = jax.block_until_ready(
-                self._verify_sampled(
+            with self._span("serve.dispatch") as d:
+                out = self._verify_sampled(
                     self.params, self.cache,
                     jnp.asarray(self.pending, jnp.int32),
                     jnp.asarray(chains), jnp.asarray(have),
                     jnp.asarray(self.live),
                     ds["temp"], ds["topk"], ds["topp"], ds["key"],
                 )
-            )
+            (new_cache, n_chain, new_pending, new_key), dt = self._wait(out, d)
             self.dstate = dict(ds, key=new_key)
         else:
-            new_cache, _, n_chain, new_pending = jax.block_until_ready(
-                self._verify(
+            with self._span("serve.dispatch") as d:
+                out = self._verify(
                     self.params, self.cache,
                     jnp.asarray(self.pending, jnp.int32),
                     jnp.asarray(chains), jnp.asarray(have),
                     jnp.asarray(self.live),
                 )
-            )
-        dt = time.perf_counter() - t0
-        self.stats["host_syncs"] += 1
-        self.stats["device_wait"] += dt
+            (new_cache, _, n_chain, new_pending), dt = self._wait(out, d)
         self.cache = new_cache
-        self.stats["target_calls"] += 1
-        self.stats["verify_time"] += dt
-        self.costs.observe_target(dt, tokens=1)   # per-round target latency
-
-        n_chain = np.asarray(n_chain)
-        new_pending = np.asarray(new_pending)
-        out: Dict[int, List[int]] = {}
-        for b in range(self.B):
-            if not self.live[b]:
-                continue
-            acc = [int(self.pending[b])] + [int(t) for t in chains[b, : n_chain[b]]]
-            self.contexts[b].extend(acc)
-            out[b] = acc
-            self.stats["tokens"] += len(acc)
-            # Eq. 4 EMA over the NEURAL drafter (the alpha paired with the
-            # neural scan's c in T_SD): observe the first neural position's
-            # outcome, and only when its PLD prefix was fully accepted —
-            # otherwise the neural token was never evaluated (DyTC's
-            # parent-accepted rule). PLD outcomes never enter this alpha.
-            pld_n = int(self._pld_have[b])
-            if have[b] > pld_n and n_chain[b] >= pld_n:
-                self.acceptance.observe(self._slot_key(b), n_chain[b] > pld_n)
-        self._host_round_telemetry(
-            n_chain + 1, np.maximum(have - self._pld_have, 0),
-            self._pld_have, self._last_limit,
-        )
-        self.pending = np.where(self.live, new_pending.astype(np.int64), self.pending)
-        self.stats["steps"] += 1
-        return out
+        with self._span("serve.commit"):
+            self.stats["target_calls"] += 1
+            self.costs.observe_target(dt, tokens=1)   # per-round latency
+            n_chain = np.asarray(n_chain)
+            new_pending = np.asarray(new_pending)
+            out: Dict[int, List[int]] = {}
+            for b in range(self.B):
+                if not self.live[b]:
+                    continue
+                acc = [int(self.pending[b])] + [
+                    int(t) for t in chains[b, : n_chain[b]]
+                ]
+                self.contexts[b].extend(acc)
+                out[b] = acc
+                self.stats["tokens"] += len(acc)
+                # Eq. 4 EMA over the NEURAL drafter (the alpha paired with
+                # the neural scan's c in T_SD): observe the first neural
+                # position's outcome, and only when its PLD prefix was
+                # fully accepted — otherwise the neural token was never
+                # evaluated (DyTC's parent-accepted rule). PLD outcomes
+                # never enter this alpha.
+                pld_n = int(self._pld_have[b])
+                if have[b] > pld_n and n_chain[b] >= pld_n:
+                    self.acceptance.observe(
+                        self._slot_key(b), n_chain[b] > pld_n
+                    )
+            self._host_round_telemetry(
+                n_chain + 1, np.maximum(have - self._pld_have, 0),
+                self._pld_have, self._last_limit,
+            )
+            self.pending = np.where(
+                self.live, new_pending.astype(np.int64), self.pending
+            )
+            self.stats["steps"] += 1
+            return out
 
     def _step_tree(self) -> Dict[int, List[int]]:
         """One DyTC round for the whole batch: PLD-seeded on-device tree
         growth (ONE fused scan dispatch), then fused verify + path commit
         (ONE target dispatch). Returns accepted tokens per live slot."""
-        chains, have = self._pld_chains()
-        limits = np.zeros(self.B, np.int32)
-        alphas = np.full(self.B, 0.5, np.float32)
-        for b in range(self.B):
-            if self.live[b]:
-                limits[b] = self._slot_tree_budget(b)
-                alphas[b] = self.acceptance.alpha(self._slot_key(b))
-        seed = tree_seed_arrays(
-            self.pending.astype(np.int32), chains, have, self.tree_bucket,
-            pld_alpha=PLD_SPEC.prior_alpha,
-        )
-        d_tokens, d_parents, d_depth, d_p_acc, d_mask, d_count = (
-            jnp.asarray(a) for a in seed
-        )
-        tokens, parents, count = seed[0], seed[1], seed[5]
-        first_neural = np.full(self.B, -1, np.int32)
-        expansions = int(limits.max(initial=0))
+        with self._span("serve.plan"):
+            chains, have = self._pld_chains()
+            limits = np.zeros(self.B, np.int32)
+            alphas = np.full(self.B, 0.5, np.float32)
+            for b in range(self.B):
+                if self.live[b]:
+                    limits[b] = self._slot_tree_budget(b)
+                    alphas[b] = self.acceptance.alpha(self._slot_key(b))
+            seed = tree_seed_arrays(
+                self.pending.astype(np.int32), chains, have, self.tree_bucket,
+                pld_alpha=PLD_SPEC.prior_alpha,
+            )
+            d_tokens, d_parents, d_depth, d_p_acc, d_mask, d_count = (
+                jnp.asarray(a) for a in seed
+            )
+            tokens, parents, count = seed[0], seed[1], seed[5]
+            first_neural = np.full(self.B, -1, np.int32)
+            expansions = int(limits.max(initial=0))
         if expansions > 0:
             c = self.costs.c_hat(
                 "tree_draft", default=float(self.draft_spec.prior_c)
             )
-            t0 = time.perf_counter()
-            out = jax.block_until_ready(self._tree_draft_fn(expansions)(
-                self.params, self.cache,
-                d_tokens, d_parents, d_depth, d_p_acc, d_mask, d_count,
-                jnp.asarray(limits), jnp.asarray(alphas),
-                jnp.asarray(max(c, 1e-3), jnp.float32),
-                jnp.asarray(self.t_min, jnp.float32),
-                self._gates,
-            ))
-            dt = time.perf_counter() - t0
-            # depth/mask stay on device (only the verify reads them); the
-            # host bookkeeping below needs tokens/parents/count/first only
-            d_tokens, d_parents, d_depth, _, d_mask, d_count, d_first = out
-            tokens, parents, count, first_neural = (
-                np.asarray(a) for a in (d_tokens, d_parents, d_count, d_first)
-            )
-            self.stats["draft_dispatches"] += 1
-            self.stats["draft_time"] += dt
-            self.stats["host_syncs"] += 1
-            self.stats["device_wait"] += dt
-            self.stats["drafted_tokens"] += int(
-                np.clip(count - have - 1, 0, None).sum()
-            )
-            # per-expansion-step latency -> the c in the Eq. 5 budgets
-            self.costs.observe("tree_draft", dt, tokens=expansions)
+            with self._span("serve.dispatch") as d:
+                out = self._tree_draft_fn(expansions)(
+                    self.params, self.cache,
+                    d_tokens, d_parents, d_depth, d_p_acc, d_mask, d_count,
+                    jnp.asarray(limits), jnp.asarray(alphas),
+                    jnp.asarray(max(c, 1e-3), jnp.float32),
+                    jnp.asarray(self.t_min, jnp.float32),
+                    self._gates,
+                )
+            out, dt = self._wait(out, d)
+            with self._span("serve.commit"):
+                # depth/mask stay on device (only the verify reads them);
+                # the host bookkeeping needs tokens/parents/count/first
+                d_tokens, d_parents, d_depth, _, d_mask, d_count, d_first = out
+                tokens, parents, count, first_neural = (
+                    np.asarray(a)
+                    for a in (d_tokens, d_parents, d_count, d_first)
+                )
+                self.stats["draft_dispatches"] += 1
+                self.stats["drafted_tokens"] += int(
+                    np.clip(count - have - 1, 0, None).sum()
+                )
+                # per-expansion-step latency -> the c in the Eq. 5 budgets
+                self.costs.observe("tree_draft", dt, tokens=expansions)
 
-        t0 = time.perf_counter()
         if self.sampling is not None:
             ds = self.dstate
-            new_cache, path, n_acc, bonus, new_key = jax.block_until_ready(
-                self._tree_verify_sampled(
+            with self._span("serve.dispatch") as d:
+                out = self._tree_verify_sampled(
                     self.params, self.cache,
                     d_tokens, d_parents, d_depth, d_mask, d_count,
                     jnp.asarray(self.live),
                     ds["temp"], ds["topk"], ds["topp"], ds["key"],
                 )
-            )
+            (new_cache, path, n_acc, bonus, new_key), dt = self._wait(out, d)
             self.dstate = dict(ds, key=new_key)
         else:
-            new_cache, path, n_acc, bonus = jax.block_until_ready(
-                self._tree_verify(
+            with self._span("serve.dispatch") as d:
+                out = self._tree_verify(
                     self.params, self.cache,
                     d_tokens, d_parents, d_depth, d_mask, d_count,
                     jnp.asarray(self.live),
                 )
-            )
-        dt = time.perf_counter() - t0
+            (new_cache, path, n_acc, bonus), dt = self._wait(out, d)
         self.cache = new_cache
-        self.stats["target_calls"] += 1
-        self.stats["verify_time"] += dt
-        self.stats["host_syncs"] += 1
-        self.stats["device_wait"] += dt
-        self.costs.observe_target(dt, tokens=1)
-
-        path, n_acc, bonus = np.asarray(path), np.asarray(n_acc), np.asarray(bonus)
-        out_toks: Dict[int, List[int]] = {}
-        for b in range(self.B):
-            if not self.live[b]:
-                continue
-            nodes = path[b, : n_acc[b]]
-            acc = [int(tokens[b, i]) for i in nodes]
-            self.contexts[b].extend(acc)
-            out_toks[b] = acc
-            self.stats["tokens"] += len(acc)
-            # Eq. 4 EMA: observe the slot's first NEURAL top-1 prediction,
-            # and only when its parent was accepted (DyTC's parent-accepted
-            # rule; the root is always accepted). When the drafter's top-1
-            # duplicated an existing PLD child, first_neural aliases that
-            # node — the outcome priced is still the neural prediction's.
-            fn = int(first_neural[b])
-            if fn >= 0:
-                node_set = {int(i) for i in nodes}
-                if int(parents[b, fn]) in node_set:
-                    self.acceptance.observe(self._slot_key(b), fn in node_set)
-        self._host_round_telemetry(
-            n_acc, np.clip(count - have - 1, 0, None), have, limits,
-        )
-        self.pending = np.where(self.live, bonus.astype(np.int64), self.pending)
-        self.stats["steps"] += 1
-        return out_toks
+        with self._span("serve.commit"):
+            self.stats["target_calls"] += 1
+            self.costs.observe_target(dt, tokens=1)
+            path, n_acc, bonus = (
+                np.asarray(path), np.asarray(n_acc), np.asarray(bonus)
+            )
+            out_toks: Dict[int, List[int]] = {}
+            for b in range(self.B):
+                if not self.live[b]:
+                    continue
+                nodes = path[b, : n_acc[b]]
+                acc = [int(tokens[b, i]) for i in nodes]
+                self.contexts[b].extend(acc)
+                out_toks[b] = acc
+                self.stats["tokens"] += len(acc)
+                # Eq. 4 EMA: observe the slot's first NEURAL top-1
+                # prediction, and only when its parent was accepted (DyTC's
+                # parent-accepted rule; the root is always accepted). When
+                # the drafter's top-1 duplicated an existing PLD child,
+                # first_neural aliases that node — the outcome priced is
+                # still the neural prediction's.
+                fn = int(first_neural[b])
+                if fn >= 0:
+                    node_set = {int(i) for i in nodes}
+                    if int(parents[b, fn]) in node_set:
+                        self.acceptance.observe(
+                            self._slot_key(b), fn in node_set
+                        )
+            self._host_round_telemetry(
+                n_acc, np.clip(count - have - 1, 0, None), have, limits,
+            )
+            self.pending = np.where(
+                self.live, bonus.astype(np.int64), self.pending
+            )
+            self.stats["steps"] += 1
+            return out_toks
 
     # --------------------------------------------------------- cascade round
     def _slot_cascade_plan(self, b: int):
@@ -1786,50 +1817,51 @@ class BatchedSpecServer:
         fused target verify + commit. Returns accepted tokens per slot."""
         bank = self.bank
         L = len(bank)
-        chains, have = self._pld_chains()
-        exp_b = np.zeros(self.B, np.int32)
-        use_rescore = np.zeros(self.B, bool)
-        alpha_eff = np.full(self.B, 0.5, np.float32)
-        resc_alphas = np.full((max(L - 1, 1), self.B), 0.5, np.float32)
-        for b in range(self.B):
-            if not self.live[b]:
-                continue
-            exp_b[b], use_rescore[b], alpha_eff[b], r_alphas = (
-                self._slot_cascade_plan(b)
+        with self._span("serve.plan"):
+            chains, have = self._pld_chains()
+            exp_b = np.zeros(self.B, np.int32)
+            use_rescore = np.zeros(self.B, bool)
+            alpha_eff = np.full(self.B, 0.5, np.float32)
+            resc_alphas = np.full((max(L - 1, 1), self.B), 0.5, np.float32)
+            for b in range(self.B):
+                if not self.live[b]:
+                    continue
+                exp_b[b], use_rescore[b], alpha_eff[b], r_alphas = (
+                    self._slot_cascade_plan(b)
+                )
+                for i, a in enumerate(r_alphas):
+                    resc_alphas[i, b] = a
+            seed = tree_seed_arrays(
+                self.pending.astype(np.int32), chains, have, self.tree_bucket,
+                pld_alpha=bank.pld.prior_alpha,
             )
-            for i, a in enumerate(r_alphas):
-                resc_alphas[i, b] = a
-        seed = tree_seed_arrays(
-            self.pending.astype(np.int32), chains, have, self.tree_bucket,
-            pld_alpha=bank.pld.prior_alpha,
-        )
-        d_tokens, d_parents, d_depth, d_p_acc, d_mask, d_count = (
-            jnp.asarray(a) for a in seed
-        )
-        first_neural = jnp.full((self.B,), -1, jnp.int32)
-        expansions = int(exp_b.max(initial=0))
-        c_draft = self.costs.c_hat("cascade_draft", default=bank.c_prior(L - 1))
+            d_tokens, d_parents, d_depth, d_p_acc, d_mask, d_count = (
+                jnp.asarray(a) for a in seed
+            )
+            first_neural = jnp.full((self.B,), -1, jnp.int32)
+            expansions = int(exp_b.max(initial=0))
+            c_draft = self.costs.c_hat(
+                "cascade_draft", default=bank.c_prior(L - 1)
+            )
         if expansions > 0:
-            t0 = time.perf_counter()
-            out = jax.block_until_ready(self._casc_draft_fn(expansions)(
-                bank.drafter.params, self.cache,
-                d_tokens, d_parents, d_depth, d_p_acc, d_mask, d_count,
-                jnp.asarray(exp_b), jnp.asarray(alpha_eff),
-                jnp.asarray(max(c_draft, 1e-3), jnp.float32),
-                jnp.asarray(self.t_min, jnp.float32),
-                self._level_gates[bank.drafter.index],
-            ))
-            dt = time.perf_counter() - t0
+            with self._span("serve.dispatch") as d:
+                out = self._casc_draft_fn(expansions)(
+                    bank.drafter.params, self.cache,
+                    d_tokens, d_parents, d_depth, d_p_acc, d_mask, d_count,
+                    jnp.asarray(exp_b), jnp.asarray(alpha_eff),
+                    jnp.asarray(max(c_draft, 1e-3), jnp.float32),
+                    jnp.asarray(self.t_min, jnp.float32),
+                    self._level_gates[bank.drafter.index],
+                )
+            out, dt = self._wait(out, d)
             (d_tokens, d_parents, d_depth, d_p_acc, d_mask, d_count,
              first_neural) = out
-            self.stats["draft_dispatches"] += 1
-            self.stats["draft_time"] += dt
-            self.stats["host_syncs"] += 1
-            self.stats["device_wait"] += dt
-            self.stats["drafted_tokens"] += int(
-                np.clip(np.asarray(d_count) - have - 1, 0, None).sum()
-            )
-            self.costs.observe("cascade_draft", dt, tokens=expansions)
+            with self._span("serve.commit"):
+                self.stats["draft_dispatches"] += 1
+                self.stats["drafted_tokens"] += int(
+                    np.clip(np.asarray(d_count) - have - 1, 0, None).sum()
+                )
+                self.costs.observe("cascade_draft", dt, tokens=expansions)
 
         # vertical rescores: just-above-drafter first, strongest level last,
         # each ONE jitted dispatch; the probe chain carries each level's
@@ -1853,20 +1885,22 @@ class BatchedSpecServer:
                 r = lvl.index
                 last_level = lvl is bank.rescorers[-1]
                 extra = tuple(samp) if samp is not None else ()
-                t0 = time.perf_counter()
                 if last_level and self.telemetry:
                     # the donated telemetry buffer rides the final fused
-                    # dispatch (re-bound in the same statement, REPRO002);
-                    # it absorbs the whole round's per-slot tallies plus
-                    # this dispatch's own Eq. 4 verdict
-                    out = jax.block_until_ready(self._rescore_verify_fn(r)(
-                        lvl.params, self.params, self.cache,
-                        d_tokens, d_parents, d_depth, d_p_acc, d_mask, d_count,
-                        probe, apply, jnp.asarray(resc_alphas[r]),
-                        self._level_gates[r], live_d,
-                        self._telem_dev, jnp.asarray(have),
-                        jnp.asarray(exp_b), *extra,
-                    ))
+                    # dispatch (re-bound below, before any read); it
+                    # absorbs the whole round's per-slot tallies plus this
+                    # dispatch's own Eq. 4 verdict
+                    with self._span("serve.dispatch") as d:
+                        out = self._rescore_verify_fn(r)(
+                            lvl.params, self.params, self.cache,
+                            d_tokens, d_parents, d_depth, d_p_acc, d_mask,
+                            d_count, probe, apply,
+                            jnp.asarray(resc_alphas[r]),
+                            self._level_gates[r], live_d,
+                            self._telem_dev, jnp.asarray(have),
+                            jnp.asarray(exp_b), *extra,
+                        )
+                    out, dt = self._wait(out, d)
                     if samp is not None:
                         (d_tokens, d_parents, d_depth, d_p_acc, d_mask,
                          d_count, lvl_node_d, probe_ok, probe_valid,
@@ -1878,12 +1912,15 @@ class BatchedSpecServer:
                          new_cache, path, n_acc, bonus,
                          self._telem_dev) = out
                 elif last_level:
-                    out = jax.block_until_ready(self._rescore_verify_fn(r)(
-                        lvl.params, self.params, self.cache,
-                        d_tokens, d_parents, d_depth, d_p_acc, d_mask, d_count,
-                        probe, apply, jnp.asarray(resc_alphas[r]),
-                        self._level_gates[r], live_d, *extra,
-                    ))
+                    with self._span("serve.dispatch") as d:
+                        out = self._rescore_verify_fn(r)(
+                            lvl.params, self.params, self.cache,
+                            d_tokens, d_parents, d_depth, d_p_acc, d_mask,
+                            d_count, probe, apply,
+                            jnp.asarray(resc_alphas[r]),
+                            self._level_gates[r], live_d, *extra,
+                        )
+                    out, dt = self._wait(out, d)
                     if samp is not None:
                         (d_tokens, d_parents, d_depth, d_p_acc, d_mask,
                          d_count, lvl_node_d, probe_ok, probe_valid,
@@ -1893,12 +1930,15 @@ class BatchedSpecServer:
                          d_count, lvl_node_d, probe_ok, probe_valid,
                          new_cache, path, n_acc, bonus) = out
                 else:
-                    out = jax.block_until_ready(self._rescore_fn(r)(
-                        lvl.params, self.cache,
-                        d_tokens, d_parents, d_depth, d_p_acc, d_mask, d_count,
-                        probe, apply, jnp.asarray(resc_alphas[r]),
-                        self._level_gates[r], *extra,
-                    ))
+                    with self._span("serve.dispatch") as d:
+                        out = self._rescore_fn(r)(
+                            lvl.params, self.cache,
+                            d_tokens, d_parents, d_depth, d_p_acc, d_mask,
+                            d_count, probe, apply,
+                            jnp.asarray(resc_alphas[r]),
+                            self._level_gates[r], *extra,
+                        )
+                    out, dt = self._wait(out, d)
                     if samp is not None:
                         (d_tokens, d_parents, d_depth, d_p_acc, d_mask,
                          d_count, lvl_node_d, probe_ok, probe_valid,
@@ -1906,130 +1946,127 @@ class BatchedSpecServer:
                     else:
                         (d_tokens, d_parents, d_depth, d_p_acc, d_mask,
                          d_count, lvl_node_d, probe_ok, probe_valid) = out
-                dt = time.perf_counter() - t0
-                self.stats["rescore_dispatches"] += 1
-                self.stats["host_syncs"] += 1
-                self.stats["device_wait"] += dt
-                if last_level:
-                    # the fused dispatch contains the target verify; its
-                    # wall time prices the TARGET round (the level's own
-                    # cost coefficient keeps its prior / last split-mode
-                    # estimate — see docs/cascade.md)
-                    self.stats["target_calls"] += 1
-                    self.stats["verify_time"] += dt
-                    self.costs.observe_target(dt, tokens=1)
-                else:
-                    self.stats["rescore_time"] += dt
-                    self.costs.observe(bank.cost_key(r), dt, tokens=1)
-                # Eq. 4: this level's verdict on level r+1's first token
-                pv, pk = np.asarray(probe_valid), np.asarray(probe_ok)
-                if not (last_level and self.telemetry):
-                    # device carriage covered only the final dispatch's
-                    # verdict — intermediate rescorers mirror theirs into
-                    # the host twin from the same arrays the trackers read
-                    self._telem_host["casc_obs"][r + 1] += pv.astype(np.int32)
-                    self._telem_host["casc_accept"][r + 1] += (
-                        pv & pk
-                    ).astype(np.int32)
-                for b in range(self.B):
-                    if pv[b]:
-                        self.acceptance.observe(
-                            bank.slot_key(r + 1, b), bool(pk[b])
+                with self._span("serve.commit"):
+                    self.stats["rescore_dispatches"] += 1
+                    if last_level:
+                        # the fused dispatch contains the target verify; its
+                        # wall time prices the TARGET round (the level's own
+                        # cost coefficient keeps its prior / last split-mode
+                        # estimate — see docs/cascade.md)
+                        self.stats["target_calls"] += 1
+                        self.costs.observe_target(dt, tokens=1)
+                    else:
+                        self.costs.observe(bank.cost_key(r), dt, tokens=1)
+                    # Eq. 4: this level's verdict on level r+1's first token
+                    pv, pk = np.asarray(probe_valid), np.asarray(probe_ok)
+                    if not (last_level and self.telemetry):
+                        # device carriage covered only the final dispatch's
+                        # verdict — intermediate rescorers mirror theirs
+                        # into the host twin from the same arrays the
+                        # trackers read
+                        self._telem_host["casc_obs"][r + 1] += pv.astype(
+                            np.int32
                         )
+                        self._telem_host["casc_accept"][r + 1] += (
+                            pv & pk
+                        ).astype(np.int32)
+                    for b in range(self.B):
+                        if pv[b]:
+                            self.acceptance.observe(
+                                bank.slot_key(r + 1, b), bool(pk[b])
+                            )
                 probe = lvl_node_d
             level_node = np.asarray(probe)
             self.cache = new_cache
         else:
-            t0 = time.perf_counter()
             if samp is not None:
-                new_cache, path, n_acc, bonus, samp[3] = jax.block_until_ready(
-                    self._tree_verify_sampled(
+                with self._span("serve.dispatch") as d:
+                    out = self._tree_verify_sampled(
                         self.params, self.cache,
                         d_tokens, d_parents, d_depth, d_mask, d_count,
                         live_d, *samp,
                     )
+                (new_cache, path, n_acc, bonus, samp[3]), dt = self._wait(
+                    out, d
                 )
             else:
-                new_cache, path, n_acc, bonus = jax.block_until_ready(
-                    self._tree_verify(
+                with self._span("serve.dispatch") as d:
+                    out = self._tree_verify(
                         self.params, self.cache,
                         d_tokens, d_parents, d_depth, d_mask, d_count,
                         live_d,
                     )
-                )
-            dt = time.perf_counter() - t0
+                (new_cache, path, n_acc, bonus), dt = self._wait(out, d)
             self.cache = new_cache
             self.stats["target_calls"] += 1
-            self.stats["verify_time"] += dt
-            self.stats["host_syncs"] += 1
-            self.stats["device_wait"] += dt
             self.costs.observe_target(dt, tokens=1)
 
-        tokens_h = np.asarray(d_tokens)
-        parents_h = np.asarray(d_parents)
-        first_h = np.asarray(first_neural)
-        path, n_acc, bonus = np.asarray(path), np.asarray(n_acc), np.asarray(bonus)
-        rescored_round = bool(use_rescore.any())
-        if not (rescored_round and self.telemetry):
-            # no rescore_verify dispatch carried the buffer this round
-            # (single-level routing, or telemetry off) — host twin carries
-            # the per-slot tallies and routing rows instead
-            self._host_round_telemetry(
-                n_acc, np.clip(np.asarray(d_count) - have - 1, 0, None),
-                have, exp_b,
-            )
-            routed = (use_rescore & self.live).astype(np.int32)
-            for lv in bank.rescorers:
-                self._telem_host["casc_routed"][lv.index] += routed
-            self._telem_host["casc_routed"][bank.drafter.index] += (
-                (exp_b > 0) & self.live
-            ).astype(np.int32)
-        out_toks: Dict[int, List[int]] = {}
-        for b in range(self.B):
-            if not self.live[b]:
-                continue
-            nodes = path[b, : n_acc[b]]
-            acc = [int(tokens_h[b, i]) for i in nodes]
-            self.contexts[b].extend(acc)
-            out_toks[b] = acc
-            self.stats["tokens"] += len(acc)
-            node_set = {int(i) for i in nodes}
-            # Eq. 4, target-facing (parent-accepted rule): on cascade
-            # rounds the observation point is the STRONGEST level's own
-            # node; on single-level rounds it is the drafter's first
-            # prediction, priced under the slot's direct tracker
-            if use_rescore[b]:
-                fn = int(level_node[b])
-                if fn >= 0 and int(parents_h[b, fn]) in node_set:
-                    self.acceptance.observe(
-                        bank.slot_key(0, b), fn in node_set
-                    )
-                    # target-facing verdict: row 0 of the cascade tallies
-                    # (the device dispatch cannot see the accepted path's
-                    # host-side membership test — always host-mirrored)
-                    self._telem_host["casc_obs"][0, b] += 1
-                    self._telem_host["casc_accept"][0, b] += int(
-                        fn in node_set
-                    )
-            else:
-                fn = int(first_h[b])
-                if fn >= 0 and int(parents_h[b, fn]) in node_set:
-                    self.acceptance.observe(bank.direct_key(b), fn in node_set)
-                    if L == 1:
-                        # a 1-level bank's direct acceptance IS its
-                        # target-facing level alpha — keep the plan's
-                        # cascade leg priced too
+        with self._span("serve.commit"):
+            tokens_h = np.asarray(d_tokens)
+            parents_h = np.asarray(d_parents)
+            first_h = np.asarray(first_neural)
+            path, n_acc, bonus = np.asarray(path), np.asarray(n_acc), np.asarray(bonus)
+            rescored_round = bool(use_rescore.any())
+            if not (rescored_round and self.telemetry):
+                # no rescore_verify dispatch carried the buffer this round
+                # (single-level routing, or telemetry off) — host twin carries
+                # the per-slot tallies and routing rows instead
+                self._host_round_telemetry(
+                    n_acc, np.clip(np.asarray(d_count) - have - 1, 0, None),
+                    have, exp_b,
+                )
+                routed = (use_rescore & self.live).astype(np.int32)
+                for lv in bank.rescorers:
+                    self._telem_host["casc_routed"][lv.index] += routed
+                self._telem_host["casc_routed"][bank.drafter.index] += (
+                    (exp_b > 0) & self.live
+                ).astype(np.int32)
+            out_toks: Dict[int, List[int]] = {}
+            for b in range(self.B):
+                if not self.live[b]:
+                    continue
+                nodes = path[b, : n_acc[b]]
+                acc = [int(tokens_h[b, i]) for i in nodes]
+                self.contexts[b].extend(acc)
+                out_toks[b] = acc
+                self.stats["tokens"] += len(acc)
+                node_set = {int(i) for i in nodes}
+                # Eq. 4, target-facing (parent-accepted rule): on cascade
+                # rounds the observation point is the STRONGEST level's own
+                # node; on single-level rounds it is the drafter's first
+                # prediction, priced under the slot's direct tracker
+                if use_rescore[b]:
+                    fn = int(level_node[b])
+                    if fn >= 0 and int(parents_h[b, fn]) in node_set:
                         self.acceptance.observe(
                             bank.slot_key(0, b), fn in node_set
                         )
+                        # target-facing verdict: row 0 of the cascade tallies
+                        # (the device dispatch cannot see the accepted path's
+                        # host-side membership test — always host-mirrored)
                         self._telem_host["casc_obs"][0, b] += 1
                         self._telem_host["casc_accept"][0, b] += int(
                             fn in node_set
                         )
-        if samp is not None:
-            # the advanced slot keys (threaded through every dispatch above)
-            # re-enter the carried state as device arrays — no host copy
-            self.dstate = dict(self.dstate, key=samp[3])
-        self.pending = np.where(self.live, bonus.astype(np.int64), self.pending)
-        self.stats["steps"] += 1
-        return out_toks
+                else:
+                    fn = int(first_h[b])
+                    if fn >= 0 and int(parents_h[b, fn]) in node_set:
+                        self.acceptance.observe(bank.direct_key(b), fn in node_set)
+                        if L == 1:
+                            # a 1-level bank's direct acceptance IS its
+                            # target-facing level alpha — keep the plan's
+                            # cascade leg priced too
+                            self.acceptance.observe(
+                                bank.slot_key(0, b), fn in node_set
+                            )
+                            self._telem_host["casc_obs"][0, b] += 1
+                            self._telem_host["casc_accept"][0, b] += int(
+                                fn in node_set
+                            )
+            if samp is not None:
+                # the advanced slot keys (threaded through every dispatch above)
+                # re-enter the carried state as device arrays — no host copy
+                self.dstate = dict(self.dstate, key=samp[3])
+            self.pending = np.where(self.live, bonus.astype(np.int64), self.pending)
+            self.stats["steps"] += 1
+            return out_toks

@@ -8,9 +8,9 @@ Host half — ``MetricsRegistry``
     absorbs the server's legacy ``stats`` dict through ``StatsView`` (a
     MutableMapping over registry counters keyed by the old names), so
     every existing ``srv.stats["round_dispatches"]``-style read keeps
-    working while the same numbers become scrapeable. ``TraceRecorder``
-    rides along: host-loop phase spans (admit / dispatch / drain / route /
-    retire) as Chrome trace-event JSON, viewable in Perfetto.
+    working while the same numbers become scrapeable. ``span`` times the
+    host phases of the loop and the server (``PHASES``): each is a
+    profiler trace event, on the device ops' clock, and a seconds counter.
 
 Device half — the round telemetry buffer
     PRs 5–7 made the steady serving round ONE donated dispatch with ZERO
@@ -38,13 +38,12 @@ from __future__ import annotations
 
 import bisect
 import contextlib
-import json
 import math
-import os
 import time
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Counter",
@@ -53,8 +52,9 @@ __all__ = [
     "MetricsRegistry",
     "StatsView",
     "STATS_METRICS",
-    "TraceRecorder",
-    "maybe_span",
+    "PHASES",
+    "PhaseSpans",
+    "span",
     "profiler_trace",
     "telemetry_schema",
     "init_device_telemetry",
@@ -80,7 +80,7 @@ def _render_labels(labels: Tuple[Tuple[str, str], ...]) -> str:
 
 class Counter:
     """A monotonically increasing value (fractional increments allowed:
-    the legacy ``*_time`` stats are second-counters)."""
+    the phase ``*_time`` stats are second-counters)."""
 
     __slots__ = ("value",)
 
@@ -152,19 +152,28 @@ class Histogram:
 
 # legacy BatchedSpecServer.stats key -> registry counter name. StatsView
 # keeps every existing stats read/mutation working against the registry.
+# The seconds keys are the host phases' counters (``PHASES``): the span of
+# each phase adds to them, nothing else does.
 STATS_METRICS: Dict[str, str] = {
     "steps": "serve_rounds_total",
     "tokens": "serve_tokens_total",
     "target_calls": "serve_target_calls_total",
     "draft_dispatches": "serve_draft_dispatches_total",
-    "draft_time": "serve_draft_seconds_total",
-    "verify_time": "serve_verify_seconds_total",
     "drafted_tokens": "serve_drafted_tokens_total",
     "rescore_dispatches": "serve_rescore_dispatches_total",
-    "rescore_time": "serve_rescore_seconds_total",
     "round_dispatches": "serve_round_dispatches_total",
     "host_syncs": "serve_host_syncs_total",
+    "loop_time": "serve_loop_seconds_total",
+    "loop_drain_time": "serve_loop_drain_seconds_total",
+    "admit_time": "serve_admit_seconds_total",
+    "route_time": "serve_route_seconds_total",
+    "retire_time": "serve_retire_seconds_total",
+    "round_time": "serve_round_seconds_total",
+    "plan_time": "serve_plan_seconds_total",
+    "dispatch_time": "serve_dispatch_seconds_total",
     "device_wait": "serve_device_wait_seconds_total",
+    "commit_time": "serve_commit_seconds_total",
+    "drain_time": "serve_drain_seconds_total",
 }
 
 # integer-semantics stats keys: reads come back as int so existing
@@ -320,75 +329,79 @@ class StatsView:
         return f"StatsView({self.copy()!r})"
 
 
-# ============================================================ trace spans
-class TraceRecorder:
-    """Chrome trace-event recorder for host-loop phases.
+# ============================================================ host spans
+# span name -> the ``stats`` key of the phase counter its seconds add to
+# (docs/observability.md has the tree). The spans nest: loop.step holds
+# the loop.* phases and serve.round; serve.round holds the serve.* ones.
+PHASES: Dict[str, str] = {
+    "loop.step": "loop_time",
+    "loop.drain": "loop_drain_time",
+    "loop.admit": "admit_time",
+    "loop.route": "route_time",
+    "loop.retire": "retire_time",
+    "serve.round": "round_time",
+    "serve.plan": "plan_time",
+    "serve.dispatch": "dispatch_time",
+    "serve.wait": "device_wait",
+    "serve.commit": "commit_time",
+    "serve.drain": "drain_time",
+}
 
-    ``span(name)`` records one complete ("ph": "X") event; ``save`` writes
-    the ``{"traceEvents": [...]}`` JSON that chrome://tracing and Perfetto
-    (https://ui.perfetto.dev) open directly. Timestamps are microseconds
-    relative to recorder creation — only ``time.perf_counter`` deltas,
-    per the REPRO005 timing discipline. Host-phase spans deliberately do
-    NOT force device syncs: a "dispatch" span times the host-side dispatch
-    of a pipelined round (device completion is accounted separately by the
-    ``device_wait`` counter at the drain points)."""
 
-    def __init__(self) -> None:
-        self.events: List[Dict[str, Any]] = []
-        self._t0 = time.perf_counter()
-        self._pid = os.getpid()
+class span:
+    """``with span("serve.wait", counter): ...`` — one host phase.
 
-    @contextlib.contextmanager
-    def span(self, name: str, **args: Any) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter()
-            ev = {
-                "name": name, "ph": "X", "pid": self._pid, "tid": 0,
-                "ts": (t0 - self._t0) * 1e6, "dur": (t1 - t0) * 1e6,
-            }
-            if args:
-                ev["args"] = args
-            self.events.append(ev)
+    Opens a ``jax.profiler.TraceAnnotation(name, **args)``, so the span
+    lands in the profiler's own trace on the clock of the device ops (the
+    annotation costs about a microsecond when no trace is being
+    captured), and on exit adds its wall seconds to ``counter`` (a
+    pre-resolved registry ``Counter``; a lookup per call would cost more
+    than the span).
+    ``t0``/``t1`` are the ``perf_counter`` readings at entry and exit."""
 
-    def instant(self, name: str, **args: Any) -> None:
-        ev = {
-            "name": name, "ph": "i", "s": "t", "pid": self._pid, "tid": 0,
-            "ts": (time.perf_counter() - self._t0) * 1e6,
+    __slots__ = ("_ann", "_counter", "t0", "t1")
+
+    def __init__(self, name: str, counter: Counter, **args: Any) -> None:
+        self._ann = TraceAnnotation(name, **args)
+        self._counter = counter
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self.t1 = time.perf_counter()
+        self._counter.value += self.t1 - self.t0
+        self._ann.__exit__(*exc)
+
+
+class PhaseSpans:
+    """The ``PHASES`` spans bound to their counters in one registry:
+    ``spans("serve.wait")`` is ``span("serve.wait", <its counter>)``."""
+
+    def __init__(self, registry: MetricsRegistry) -> None:
+        self._counters = {
+            name: registry.counter(STATS_METRICS[key])
+            for name, key in PHASES.items()
         }
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
 
-    def to_json(self) -> Dict[str, Any]:
-        return {"traceEvents": list(self.events), "displayTimeUnit": "ms"}
-
-    def save(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.to_json(), f)
-
-
-def maybe_span(trace: Optional[TraceRecorder], name: str, **args: Any):
-    """``with maybe_span(trace, "drain"):`` — a no-op when tracing is off,
-    so call sites don't branch."""
-    if trace is None:
-        return contextlib.nullcontext()
-    return trace.span(name, **args)
+    def __call__(self, name: str, **args: Any) -> span:
+        return span(name, self._counters[name], **args)
 
 
 @contextlib.contextmanager
 def profiler_trace(log_dir: Optional[str]) -> Iterator[None]:
     """Optional ``jax.profiler.trace`` hook: profiles the wrapped region
-    into ``log_dir`` (TensorBoard/XPlane format) when a directory is
-    given; a no-op otherwise."""
+    into ``log_dir`` when a directory is given (the XPlane file, and a
+    Perfetto trace that shows the host spans above beside the device
+    ops); a no-op otherwise."""
     if not log_dir:
         yield
         return
     import jax
 
-    with jax.profiler.trace(log_dir):
+    with jax.profiler.trace(log_dir, create_perfetto_trace=True):
         yield
 
 
@@ -558,29 +571,28 @@ def fold_telemetry(
     delta: Dict[str, np.ndarray],
     prefix: str = "serve",
 ) -> None:
-    """Fold a drained per-slot delta into labeled registry counters."""
-    per_slot = {
+    """Fold a drained per-slot delta into registry counters, summed over
+    slots: unlabelled totals, the budget histogram by ``budget``, and the
+    cascade tallies by ``level``. The per-slot arrays stay readable
+    through ``telemetry_totals``."""
+    totals = {
         "rounds": f"{prefix}_slot_rounds_total",
         "accepted": f"{prefix}_slot_accepted_tokens_total",
         "drafted": f"{prefix}_slot_drafted_tokens_total",
         "pld_tokens": f"{prefix}_slot_pld_tokens_total",
         "pld_hit_rounds": f"{prefix}_slot_pld_hit_rounds_total",
     }
-    for key, name in per_slot.items():
+    for key, name in totals.items():
         arr = delta.get(key)
-        if arr is None:
-            continue
-        for b, v in enumerate(arr):
-            if v:
-                registry.counter(name, slot=b).inc(int(v))
+        if arr is not None and arr.any():
+            registry.counter(name).inc(int(arr.sum()))
     bh = delta.get("budget_hist")
     if bh is not None:
-        for b in range(bh.shape[0]):
-            for j in range(bh.shape[1]):
-                if bh[b, j]:
-                    registry.counter(
-                        f"{prefix}_draft_budget_rounds_total", slot=b, budget=j
-                    ).inc(int(bh[b, j]))
+        for j, v in enumerate(bh.sum(axis=0).tolist()):
+            if v:
+                registry.counter(
+                    f"{prefix}_draft_budget_rounds_total", budget=j
+                ).inc(v)
     per_level = {
         "casc_routed": f"{prefix}_cascade_routed_rounds_total",
         "casc_obs": f"{prefix}_cascade_obs_total",
@@ -590,9 +602,6 @@ def fold_telemetry(
         arr = delta.get(key)
         if arr is None:
             continue
-        for lvl in range(arr.shape[0]):
-            for b in range(arr.shape[1]):
-                if arr[lvl, b]:
-                    registry.counter(name, level=lvl, slot=b).inc(
-                        int(arr[lvl, b])
-                    )
+        for lvl, v in enumerate(arr.sum(axis=1).tolist()):
+            if v:
+                registry.counter(name, level=lvl).inc(v)
