@@ -40,7 +40,7 @@ MAX_BATCH = 4
 srv = BatchedSpecServer(cfg, params, max_batch=MAX_BATCH, max_len=512,
                         draft_k=4, mode="cascade_fused")
 print("cascade levels:", " > ".join(l.name for l in srv.bank.levels), "> PLD",
-      f"(int8 sim copies: {srv.bank.param_bytes/1e6:.1f} MB)")
+      f"(int8 copy: {srv.bank.param_bytes/1e6:.1f} MB)")
 sched = RequestScheduler(max_batch=MAX_BATCH)
 for r in requests:
     sched.submit(r)

@@ -33,6 +33,7 @@ from repro.core.latency import (
 from repro.core.pld import PromptLookup
 from repro.core.tree import DraftTree, bucket_for, tree_seed_device
 from repro.core import verify as verify_lib
+from repro.kernels.int8_matmul import quantize_weight
 from repro.models import model as M
 from repro.models.shard_utils import constrain, data_axis
 
@@ -40,19 +41,16 @@ import dataclasses
 
 
 def fake_quant_int8(params: dict) -> dict:
-    """Per-output-channel symmetric int8 weight fake-quantization (QSpec sim)."""
+    """The int8 draft level as the CPU simulates it (QSpec sim): every
+    dense-MLP weight replaced by its dequantized int8 image — per layer,
+    per column over the whole K, exactly the image the W8A8 kernel runs
+    on (``kernels.int8_matmul.quantize_weight``). Every other leaf is the
+    target's own object."""
 
-    def q(w):
-        if not isinstance(w, jax.Array) or w.dtype not in (jnp.float32, jnp.bfloat16):
-            return w
-        if w.ndim < 2:
-            return w
-        w32 = w.astype(jnp.float32)
-        scale = jnp.max(jnp.abs(w32), axis=tuple(range(w.ndim - 1)), keepdims=True) / 127.0
-        scale = jnp.maximum(scale, 1e-8)
-        return (jnp.round(w32 / scale).clip(-127, 127) * scale).astype(w.dtype)
+    def sim(w):
+        return jax.device_put(quantize_weight(w).dequantize(w.dtype), w.sharding)
 
-    return jax.tree.map(q, params)
+    return M.map_mlp_weights(params, sim)
 
 
 DRAFT_KV_MODES = ("recompute", "carry")

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_decode import flash_decode_paged_partial, flash_decode_partial
-from repro.kernels.int8_matmul import int8_matmul, quantize_cols, quantize_rows
+from repro.kernels.int8_matmul import Int8Weight, int8_matmul, quantize_rows
 from repro.kernels.tree_attention import tree_attention_partial
 
 
@@ -151,10 +151,12 @@ def paged_verify_attention(
 
 @functools.partial(jax.jit, static_argnames=("interpret", "k_axis"))
 def quantized_matmul(
-    x: jax.Array, w: jax.Array, *, interpret: bool | None = None,
+    x: jax.Array, w: Int8Weight, *, interpret: bool | None = None,
     k_axis: str | None = None,
 ) -> jax.Array:
-    """W8A8 dynamic quantized x @ w with padding to 128-tiles.
+    """W8A8 x @ w on the weight's int8 image, in ``x``'s dtype: the
+    activations are quantized per row on each call, the weight was
+    quantized once (``quantize_weight``). Pads to 128-tiles.
 
     ``interpret`` defaults to backend-aware: compiled on TPU, interpreter
     everywhere else (the kernel only lowers on TPU) — callers on TPU get
@@ -162,18 +164,20 @@ def quantized_matmul(
     override (e.g. CPU parity tests force ``interpret=True``).
 
     ``k_axis``: inside ``shard_map`` with the contraction dim split over
-    that mesh axis, the scales are taken over the whole K and the partial
-    products summed across the axis — the unsplit result on every shard.
+    that mesh axis, the row scales are taken over the whole K (the column
+    scales already are) and the float32 partial products summed across
+    the axis — the unsplit result on every shard.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    M0, K0 = x.shape
-    N0 = w.shape[1]
+    M0, N0 = x.shape[0], w.q.shape[1]
     x_q, xs = quantize_rows(x, k_axis)
-    w_q, ws = quantize_cols(w, k_axis)
     x_q = _pad_to(_pad_to(x_q, 0, 128), 1, 128)
-    w_q = _pad_to(_pad_to(w_q, 0, 128), 1, 128)
     xs = _pad_to(xs, 0, 128, value=1.0)
-    ws = _pad_to(ws, 1, 128, value=1.0)
-    out = int8_matmul(x_q, w_q, xs, ws, interpret=interpret)[:M0, :N0]
-    return out if k_axis is None else jax.lax.psum(out, k_axis)
+    w_q = _pad_to(_pad_to(w.q, 0, 128), 1, 128)
+    ws = _pad_to(w.scale, 1, 128, value=1.0)
+    out = int8_matmul(
+        x_q, w_q, xs, ws, interpret=interpret,
+        out_dtype=x.dtype if k_axis is None else jnp.float32,
+    )[:M0, :N0]
+    return out if k_axis is None else jax.lax.psum(out, k_axis).astype(x.dtype)

@@ -6,6 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.int8_matmul import Int8Weight
+
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-5) -> jax.Array:
     dt = x.dtype
@@ -36,44 +38,63 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 
 # ----------------------------------------------------------------------- MLP
-def _mm(x: jax.Array, w: jax.Array, quantize, w_axes=(None, None)) -> jax.Array:
+def _mm(x: jax.Array, w, quantize, w_axes=(None, None)) -> jax.Array:
     """(..., d) @ (d, f), optionally through the W8A8 Pallas kernel.
 
-    ``quantize="int8"`` routes the matmul through
-    ``kernels.ops.quantized_matmul`` (dynamic per-row activation / per-col
-    weight int8 — the ActivationQuant DSIA's TPU execution; off-TPU the
-    kernel runs interpreted, so CPU callers simulate with fake-quantized
-    weights instead and never set the flag on hot paths).
+    ``quantize="int8"`` takes ``w`` as its int8 image
+    (``kernels.int8_matmul.Int8Weight``, quantized once when the draft
+    level was built) and routes the matmul through
+    ``kernels.ops.quantized_matmul``, which quantizes only the activations
+    (per row) — the ActivationQuant DSIA's TPU execution. Off-TPU the
+    kernel runs interpreted, so CPU callers simulate with the dequantized
+    image instead and never set the flag on hot paths.
 
     Under a mesh in context the partitioner cannot split the kernel, so it
     runs under ``shard_map`` with ``w`` split as ``w_axes`` pins it (batch
     over the data axes): a column split keeps its output columns, a
-    contraction split quantizes on whole-K scales and sums the partials.
+    contraction split sums the partials (the column scales already span
+    the whole K; the row scales are taken across the split).
     """
     if quantize is None:
         return jnp.einsum("...d,df->...f", x, w)
     if quantize != "int8":
         raise ValueError(f"unsupported quantize mode {quantize!r}")
+    if not isinstance(w, Int8Weight):
+        raise TypeError(
+            "quantize='int8' takes the weight's int8 image "
+            "(kernels.int8_matmul.quantize_weight), not a float weight"
+        )
     from repro.kernels.ops import quantized_matmul
     from repro.models.shard_utils import DATA_AXES, _mesh_axes, resolve_spec
 
     def mm(x, w, k_axis=None):
         lead = x.shape[:-1]
         out = quantized_matmul(x.reshape(-1, x.shape[-1]), w, k_axis=k_axis)
-        return out.reshape(*lead, w.shape[-1]).astype(x.dtype)
+        return out.reshape(*lead, out.shape[-1])
 
     if not _mesh_axes():
         return mm(x, w)
     from jax.sharding import PartitionSpec as P
 
-    wk, wn = resolve_spec(w.shape, *w_axes)
+    wk, wn = resolve_spec(w.q.shape, *w_axes)
     batch = resolve_spec(x.shape[:1], DATA_AXES) + (None,) * (x.ndim - 2)
     if wk is not None:
         mm = functools.partial(mm, k_axis=wk)
     return jax.shard_map(
-        mm, in_specs=(P(*batch, wk), P(wk, wn)), out_specs=P(*batch, wn),
-        check_vma=False,
+        mm, in_specs=(P(*batch, wk), Int8Weight(P(wk, wn), P(None, wn))),
+        out_specs=P(*batch, wn), check_vma=False,
     )(x, w)
+
+
+def _pin(w, k_axis, n_axis):
+    """``constrain_full`` for a float weight or an int8 image (its scales
+    follow the weight's columns)."""
+    from repro.models.shard_utils import constrain_full
+
+    if isinstance(w, Int8Weight):
+        return Int8Weight(constrain_full(w.q, k_axis, n_axis),
+                          constrain_full(w.scale, None, n_axis))
+    return constrain_full(w, k_axis, n_axis)
 
 
 def mlp_apply(
@@ -87,14 +108,12 @@ def mlp_apply(
     MLP carries the bulk of the stack's matmul FLOPs; attention projections
     and the LM head stay in the model dtype).
     """
-    from repro.models.shard_utils import constrain_full
-
     fn = jax.nn.silu if act == "silu" else jax.nn.gelu
     col, row = (None, "model"), ("model", None)
-    w_up = constrain_full(params["w_up"], *col)
-    w_down = constrain_full(params["w_down"], *row)
+    w_up = _pin(params["w_up"], *col)
+    w_down = _pin(params["w_down"], *row)
     if gated:
-        w_gate = constrain_full(params["w_gate"], *col)
+        w_gate = _pin(params["w_gate"], *col)
         g = fn(_mm(x, w_gate, quantize, col))
         u = _mm(x, w_up, quantize, col)
         return _mm(g * u, w_down, quantize, row)

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config.base import AttentionKind, BlockKind, ModelConfig
+from repro.kernels.int8_matmul import quantize_weight
 from repro.models import attention as attn_lib
 from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
@@ -147,6 +148,26 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         segs.append(jax.vmap(init_repeat)(seg_keys))
     params["segments"] = segs
     return params
+
+
+def map_mlp_weights(params: dict, fn) -> dict:
+    """``params`` with ``fn`` applied to every dense-MLP weight (each a
+    stacked (R, K, N) leaf of a segment); every other leaf is the same
+    object. MoE experts are no dense MLP and stay as they are."""
+    def layer(p):
+        if "mlp" not in p:
+            return p
+        return {**p, "mlp": {k: fn(w) for k, w in p["mlp"].items()}}
+
+    return {**params,
+            "segments": [[layer(p) for p in seg] for seg in params["segments"]]}
+
+
+def int8_mlp_params(params: dict) -> dict:
+    """The W8A8 draft level's params: every dense-MLP weight replaced by its
+    int8 image (``quantize_weight``: per layer and column over the whole
+    K); every other leaf is the target's own object."""
+    return map_mlp_weights(params, quantize_weight)
 
 
 # ====================================================================== cache
@@ -659,8 +680,9 @@ def decode_step(
     A 3-D tree mask carries one ancestor-closure per sequence (batched tree
     verification); paired with a (B, T) ``q_pos`` of per-node depths.
     ``quantize="int8"`` routes the dense-MLP matmuls through the Pallas
-    W8A8 kernel (ActivationQuant DSIA drafting; TPU-compiled — off-TPU
-    callers simulate with ``engine.fake_quant_int8`` params instead).
+    W8A8 kernel (ActivationQuant DSIA drafting; TPU-compiled). ``params``
+    then hold the MLP weights' int8 images (``int8_mlp_params``); off-TPU
+    callers simulate with ``engine.fake_quant_int8`` params instead.
 
     Incremental mode (``draft_kv="carry"`` in the engine scans): pass
     ``staged_kv`` — a carried pytree with the same structure a previous

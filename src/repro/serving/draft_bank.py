@@ -9,16 +9,19 @@ dispatch directly:
   - **gates** — the per-layer 0/1 vector as a device-ready float array
     (layer-sparsity / early-exit levels share the target's params and
     executable, exactly like ``chain_fused``/``tree_fused`` drafting);
-  - **int8 levels** — execution is backend-aware. On TPU the level shares
-    the ORIGINAL params and sets ``quantize="int8"`` on its decode calls,
-    which routes the dense-MLP matmuls through the Pallas
-    ``kernels.quantized_matmul`` W8A8 kernel (dynamic quantization in the
-    kernel: no second parameter copy in HBM). Off-TPU the kernel would run
-    interpreted (orders of magnitude slower than XLA), so the bank
-    materializes a fake-quantized parameter copy ONCE via
-    ``engine.fake_quant_int8`` — the CPU numerics simulation of the same
-    contract (``tests/test_int8_parity.py`` pins the two paths together).
-    ``param_bytes`` reports the memory cost of every materialized copy;
+  - **int8 levels** — own a copy of the dense-MLP weights, made ONCE at
+    bank build; every other leaf is the target's own object. Execution is
+    backend-aware. On TPU the copy is each MLP weight's int8 image
+    (``models.model.int8_mlp_params``: int8 (R, K, N) plus float32
+    (R, 1, N) scales, per layer and column over the whole K) and the
+    level sets ``quantize="int8"`` on its decode calls, which
+    routes the MLP matmuls through the Pallas ``kernels.quantized_matmul``
+    W8A8 kernel: only the activations are quantized per call. Off-TPU the
+    kernel would run interpreted (orders of magnitude slower than XLA),
+    so the copy is the dequantized image instead
+    (``engine.fake_quant_int8``) — the CPU numerics simulation of the
+    same contract (``tests/test_int8_parity.py`` pins the two paths
+    together). ``param_bytes`` reports the bytes of the copy;
   - **attn_override** — StreamingAttention levels carry the override dict
     that ``models.model.decode_step`` applies to full-attention layers.
 
@@ -30,7 +33,7 @@ executes on device.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence
+from typing import List, Optional, Sequence
 
 import jax
 import numpy as np
@@ -38,6 +41,7 @@ import numpy as np
 from repro.config.base import ModelConfig
 from repro.core.dsia import DraftSpec, PLD_SPEC
 from repro.core.engine import fake_quant_int8
+from repro.models import model as M
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,7 +53,7 @@ class DraftLevel:
     gates: Optional[np.ndarray]      # (num_layers,) f32, None = all layers on
     quantize: Optional[str]          # "int8" -> W8A8 kernel path at decode
     attn_override: Optional[dict]    # {"kind","window","sink"} or None
-    owns_params: bool                # True iff ``params`` is a quantized copy
+    owns_params: bool                # True iff ``params`` holds an int8 copy
 
     @property
     def name(self) -> str:
@@ -65,9 +69,9 @@ class DraftBank:
         only sensible in parity tests);
       - ``"sim"``    — force the fake-quant parameter copy.
 
-    ``param_sharding`` (a NamedSharding tree congruent with ``params``)
-    places any materialized int8 copy like the target weights, so sharded
-    servers keep every cascade level tensor-parallel on the same mesh.
+    The int8 copy is placed like the target weights it was made from
+    (``quantize_weight`` reads each weight's sharding), so sharded servers
+    keep every cascade level tensor-parallel on the same mesh.
     """
 
     def __init__(
@@ -77,7 +81,6 @@ class DraftBank:
         hierarchy: Sequence[DraftSpec],
         *,
         int8_exec: str = "auto",
-        param_sharding=None,
     ):
         if int8_exec not in ("auto", "kernel", "sim"):
             raise ValueError(f"unknown int8_exec {int8_exec!r}")
@@ -89,45 +92,40 @@ class DraftBank:
         if not neural:
             raise ValueError("hierarchy has no neural level to execute")
         self.pld: DraftSpec = retrieval[0] if retrieval else PLD_SPEC
-        self.param_bytes = 0
         self.levels: List[DraftLevel] = []
-        quant_cache: Dict[int, dict] = {}    # share one int8 copy per base
+        int8_params: Optional[dict] = None   # one int8 copy, shared
         for i, spec in enumerate(neural):
             gates = None
             if spec.gates is not None:
                 gates = spec.gates_array(cfg.num_layers)
-            level_params, quantize, owns = params, None, False
+            level_params, quantize = params, None
             if spec.quantize is not None:
                 if spec.quantize != "int8":
                     raise ValueError(
                         f"level {spec.name!r}: unsupported quantize "
                         f"{spec.quantize!r} (only 'int8')"
                     )
+                if int8_params is None:
+                    int8_params = (
+                        M.int8_mlp_params(params)
+                        if int8_exec == "kernel" else fake_quant_int8(params)
+                    )
+                level_params = int8_params
                 if int8_exec == "kernel":
-                    quantize = "int8"        # dynamic in-kernel quantization
-                else:
-                    if id(params) not in quant_cache:
-                        q = fake_quant_int8(params)
-                        if param_sharding is not None:
-                            # int8 sim copies inherit the target's mesh
-                            # placement — the fake-quant tree is congruent
-                            # with params, so the same sharding tree applies
-                            q = jax.device_put(q, param_sharding)
-                        quant_cache[id(params)] = q
-                    level_params, owns = quant_cache[id(params)], True
+                    quantize = "int8"
             override = None
             if spec.attn_override is not None:
                 kind, window, sink = spec.attn_override
                 override = {"kind": kind, "window": window, "sink": sink}
             self.levels.append(DraftLevel(
                 index=i, spec=spec, params=level_params, gates=gates,
-                quantize=quantize, attn_override=override, owns_params=owns,
+                quantize=quantize, attn_override=override,
+                owns_params=level_params is not params,
             ))
+        shared = {id(leaf) for leaf in jax.tree.leaves(params)}
         self.param_bytes = sum(
-            leaf.nbytes
-            for p in quant_cache.values()
-            for leaf in jax.tree.leaves(p)
-            if hasattr(leaf, "nbytes")
+            leaf.nbytes for leaf in jax.tree.leaves(int8_params)
+            if id(leaf) not in shared
         )
 
     # ------------------------------------------------------------- accessors

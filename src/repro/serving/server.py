@@ -417,7 +417,6 @@ class BatchedSpecServer:
                     hierarchy if hierarchy is not None
                     else build_hierarchy(cfg, "mixing"),
                     int8_exec=int8_exec,
-                    param_sharding=self._param_sharding,
                 )
                 # one hedge sibling + one extension node per rescore level
                 extra = 2 * len(self.bank.rescorers)

@@ -6,7 +6,10 @@ import pytest
 
 from repro.kernels import quantized_matmul, verify_attention
 from repro.kernels import ref as R
-from repro.kernels.int8_matmul import quantize_cols, quantize_rows
+from repro.kernels.int8_matmul import (
+    TILE_BYTES, VMEM_BUDGET, VMEM_LIMIT, _vmem_bytes, choose_blocks, int8_matmul,
+    quantize_rows, quantize_weight,
+)
 
 
 def _mk(B, T, H, KV, hd, S, dtype, seed=0, pos=None, tree=True):
@@ -211,16 +214,56 @@ def test_flash_decode_paged_matches_dense(kind, window, sink):
     assert bool(jnp.all(ap == ad) and jnp.all(mp == md) and jnp.all(lp == ld))
 
 
-@pytest.mark.parametrize("M,K,N", [(8, 16, 8), (100, 200, 300), (128, 128, 128), (1, 512, 64)])
-def test_int8_matmul_matches_oracle(M, K, N):
+@pytest.mark.parametrize("M,K,N,dtype", [
+    (8, 16, 8, jnp.float32), (100, 200, 300, jnp.float32),
+    (128, 128, 128, jnp.float32), (1, 512, 64, jnp.float32),
+    # M > 128 at starcoder2-3b's 1:4 MLP widths scaled down, in the
+    # served dtype (the epilogue writes bf16)
+    (384, 768, 3072, jnp.bfloat16),
+])
+def test_int8_matmul_matches_oracle(M, K, N, dtype):
     k1, k2 = jax.random.split(jax.random.PRNGKey(1))
-    x = jax.random.normal(k1, (M, K))
-    w = jax.random.normal(k2, (K, N))
-    out = quantized_matmul(x, w, interpret=True)
+    x = jax.random.normal(k1, (M, K), dtype)
+    w = jax.random.normal(k2, (K, N), dtype)
+    image = quantize_weight(w)
+    out = quantized_matmul(x, image, interpret=True)
+    assert out.dtype == dtype
     xq, xs = quantize_rows(x)
-    wq, ws = quantize_cols(w)
-    ref = R.ref_int8_matmul(xq, wq, xs, ws)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4, rtol=1e-4)
+    ref = R.ref_int8_matmul(xq, image.q, xs, image.scale).astype(dtype)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32),
+                               atol=1e-4, rtol=1e-4)
     # and the quantization error vs f32 is small
-    rel = float(jnp.mean(jnp.abs(out - x @ w)) / jnp.mean(jnp.abs(x @ w)))
+    exact = x.astype(jnp.float32) @ w.astype(jnp.float32)
+    rel = float(jnp.mean(jnp.abs(out - exact)) / jnp.mean(jnp.abs(exact)))
     assert rel < 0.05
+
+
+def test_int8_matmul_blocks_tile_the_call():
+    """A grid of several (M, N) blocks writes the same result as the
+    chosen blocks, which here cover the call in one."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(2))
+    xq, xs = quantize_rows(jax.random.normal(k1, (256, 384)))
+    image = quantize_weight(jax.random.normal(k2, (384, 512)))
+    args = (xq, image.q, xs, image.scale)
+    assert choose_blocks(256, 384, 512, 4) == (256, 512)
+    whole = int8_matmul(*args, interpret=True)
+    tiled = int8_matmul(*args, block_m=128, block_n=128, interpret=True)
+    assert np.array_equal(np.asarray(whole), np.asarray(tiled))
+    np.testing.assert_allclose(np.asarray(whole), np.asarray(R.ref_int8_matmul(*args)),
+                               atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("K,N", [
+    (3072, 12288), (12288, 3072),      # starcoder2-3b w_up, w_down
+    (6144, 16384), (16384, 6144),      # internlm2-20b w_up/w_gate, w_down
+])
+@pytest.mark.parametrize("M", [128, 256, 512, 1024])
+def test_int8_blocks_divide_and_fit(M, K, N):
+    """The tile chooser at served widths: blocks divide the padded shapes,
+    one M block holds a draft step's rows (each weight byte read once a
+    call), weight tiles of a few MiB, everything within the VMEM budget."""
+    bm, bn = choose_blocks(M, K, N, 2)
+    assert M % bm == 0 and N % bn == 0 and bn % 128 == 0
+    assert bm == min(M, 512)
+    assert 2 << 20 <= K * bn <= TILE_BYTES
+    assert _vmem_bytes(bm, bn, K, 2) <= VMEM_BUDGET < VMEM_LIMIT

@@ -4,8 +4,9 @@ A Mosaic kernel cannot be split by the SPMD partitioner, so on a mesh the
 tree-verify pass and the W8A8 MLP matmuls run per shard (KV heads / weight
 columns / the contraction dim over ``model``, batch over ``data``). Here
 they run in interpret mode on forced host devices and must reproduce the
-single-device result: the tree pass exactly, the W8A8 MLP up to the order
-of the f32 sum of its contraction-split partials.
+single-device result: the tree pass exactly, the W8A8 MLP (on int8 images
+quantized over the whole K before sharding) up to the order of the f32 sum
+of its contraction-split partials.
 
 Runs in a SUBPROCESS: the forced device count must be set before jax
 initializes, and the rest of the suite must keep seeing one device.
@@ -30,10 +31,12 @@ SCRIPT = textwrap.dedent(
     import jax, jax.numpy as jnp, numpy as np
     from repro.launch.mesh import mesh_from_spec
     from repro.models.attention import decode_attention
+    from repro.kernels.int8_matmul import quantize_weight
     from repro.models.layers import mlp_apply, mlp_init
 
     rng = np.random.default_rng(0)
-    p = mlp_init(jax.random.PRNGKey(0), 256, 512, True, jnp.float32)
+    p = {k: quantize_weight(w) for k, w in
+         mlp_init(jax.random.PRNGKey(0), 256, 512, True, jnp.float32).items()}
     x = jnp.asarray(rng.normal(size=(4, 6, 256)), jnp.float32)
     mlp = jax.jit(functools.partial(mlp_apply, act="silu", gated=True,
                                     quantize="int8"))

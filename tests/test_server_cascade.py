@@ -214,16 +214,17 @@ def test_draft_bank_materialization_sim_vs_kernel():
     assert cheap.owns_params and cheap.quantize is None
     assert cheap.params is not PARAMS         # one materialized int8 copy
     assert bank_sim.param_bytes > 0
-    # the copy is actually fake-quantized
-    w0 = jax.tree.leaves(PARAMS["segments"][0])[0]
-    wq = jax.tree.leaves(cheap.params["segments"][0])[0]
+    # the copy's MLP weights are actually fake-quantized
+    w0 = PARAMS["segments"][0][0]["mlp"]["w_up"]
+    wq = cheap.params["segments"][0][0]["mlp"]["w_up"]
     assert not np.allclose(np.asarray(w0), np.asarray(wq))
 
     bank_k = DraftBank(CFG, PARAMS, HIER, int8_exec="kernel")
     cheap_k = bank_k.levels[-1]
-    assert cheap_k.quantize == "int8" and not cheap_k.owns_params
-    assert cheap_k.params is PARAMS           # dynamic in-kernel quantization
-    assert bank_k.param_bytes == 0
+    assert cheap_k.quantize == "int8" and cheap_k.owns_params
+    assert cheap_k.params is not PARAMS       # int8 MLP images, made once
+    # int8 images: a quarter of the float32 weights, plus the scales
+    assert 0 < bank_k.param_bytes < bank_sim.param_bytes
 
     with pytest.raises(ValueError, match="int8_exec"):
         DraftBank(CFG, PARAMS, HIER, int8_exec="gpu")

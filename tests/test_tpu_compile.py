@@ -21,6 +21,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.kernels.flash_decode import flash_decode_paged_partial, flash_decode_partial
+from repro.kernels.int8_matmul import Int8Weight
 from repro.kernels.ops import paged_verify_attention, quantized_matmul, verify_attention
 from repro.kernels.tree_attention import tree_attention_partial
 
@@ -123,13 +124,17 @@ def test_verify_ops_compile(one_chip, paged):
 
 
 def test_quantized_matmul_compiles(one_chip):
-    """W8A8 at the starcoder2-3b MLP width (the cascade's int8 level)."""
-    text = _compiled_text(
-        functools.partial(quantized_matmul, interpret=False),
-        _spec((B * T, 3072), jnp.bfloat16, one_chip),
-        _spec((3072, 12288), jnp.bfloat16, one_chip),
-    )
-    assert "tpu_custom_call" in text
+    """W8A8 at the starcoder2-3b MLP widths (the cascade's int8 level:
+    w_up, then w_down), on the weight's int8 image, with the blocks
+    ``choose_blocks`` picks for a draft step's rows."""
+    for K, N in [(3072, 12288), (12288, 3072)]:
+        text = _compiled_text(
+            functools.partial(quantized_matmul, interpret=False),
+            _spec((B * T, K), jnp.bfloat16, one_chip),
+            Int8Weight(_spec((K, N), jnp.int8, one_chip),
+                       _spec((1, N), jnp.float32, one_chip)),
+        )
+        assert "tpu_custom_call" in text
 
 
 @pytest.fixture
@@ -175,21 +180,61 @@ def test_tree_pass_on_mesh_needs_no_collective(mesh4):
 
 def test_int8_mlp_on_mesh_gathers_no_weights(mesh4):
     """The cascade's int8 level on model=4 at internlm2-20b widths (gated
-    SiLU MLP, 6144 x 16384): all three W8A8 matmuls compile per shard. The
-    only collectives are the all-reduces of the down projection's
-    contraction split (whole-K scales and the partial sums), never a
-    gather of the weights."""
+    SiLU MLP, 6144 x 16384, int8 images): all three W8A8 matmuls compile
+    per shard. The only collectives are the all-reduces of the down
+    projection's contraction split (the activations' whole-K row scales
+    and the partial sums), never a gather of the weights."""
     from repro.models.layers import mlp_apply
 
     d, f = 6144, 16384
     def sd(shape, *spec):
         return _mesh_spec(mesh4, shape, jnp.bfloat16, *spec)
 
-    p = {"w_up": sd((d, f), None, "model"), "w_gate": sd((d, f), None, "model"),
-         "w_down": sd((f, d), "model", None)}
+    def image(shape, k, n):
+        return Int8Weight(
+            _mesh_spec(mesh4, shape, jnp.int8, k, n),
+            _mesh_spec(mesh4, (1, shape[1]), jnp.float32, None, n))
+
+    p = {"w_up": image((d, f), None, "model"),
+         "w_gate": image((d, f), None, "model"),
+         "w_down": image((f, d), "model", None)}
     mlp = functools.partial(mlp_apply, act="silu", gated=True, quantize="int8")
     with jax.sharding.set_mesh(mesh4):
         text = _compiled_text(mlp, p, sd((B, T, d)))
     assert text.count("tpu_custom_call") >= 3
     assert "all-reduce" in text
     assert not re.search(r"all-gather|all-to-all", text)
+
+
+def test_int8_drafter_kernel_reads_its_weight_from_hbm(one_chip, monkeypatch):
+    """The int8 drafter's decode step at starcoder2-3b widths (two layers,
+    scanned): every W8A8 kernel call takes its layer's int8 weight in HBM.
+    No other op stages the weight in VMEM (``S(1)``) ahead of the kernel,
+    so the kernel's own time covers reading it, as its roofline counts."""
+    import dataclasses
+
+    from repro.config import get_config
+    from repro.models import model as M
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_config("starcoder2-3b"), num_layers=2)
+
+    def on_chip(a):
+        return _spec(a.shape, a.dtype, one_chip)
+
+    params = jax.eval_shape(lambda k: M.init_params(cfg, k), jax.random.PRNGKey(0))
+    qparams = jax.tree.map(on_chip, jax.eval_shape(M.int8_mlp_params, params))
+    cache = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: M.init_cache(cfg, B, 256, dtype=jnp.bfloat16, paged=True,
+                             page_size=PAGE)))
+    step = functools.partial(M.decode_step, cfg, quantize="int8")
+    text = _compiled_text(lambda p, c, t: step(p, c, t)[0], qparams, cache,
+                          _spec((B, T), jnp.int32, one_chip))
+    layouts = dict(re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (\S+)", text, re.M))
+    calls = [line for line in text.splitlines()
+             if "int8_matmul" in line and "custom-call(" in line]
+    assert len(calls) == 2
+    for line in calls:
+        weight = re.search(r"custom-call\(([^)]*)\)", line).group(1).split(",")[1]
+        layout = layouts[weight.strip().lstrip("%")]
+        assert layout.startswith("s8[") and "S(1)" not in layout, (weight, layout)
